@@ -111,6 +111,7 @@ from repro.checkpoint.checkpointer import Checkpointer  # noqa: E402
 from repro.checkpoint.wal import WriteAheadLog  # noqa: E402
 from repro.configs import get_config, reduced  # noqa: E402
 from repro.distributed.sharding import make_mesh  # noqa: E402
+from repro.launch import compile_cache  # noqa: E402
 from repro.runtime.fault_tolerance import StragglerWatchdog  # noqa: E402
 from repro.serving import (ArrivalConfig, BatcherConfig,  # noqa: E402
                            BindingExecutor, BreakerConfig, Bucket,
@@ -971,6 +972,7 @@ def main() -> None:
                     help="pages audited per maintenance turn (--scrub; "
                          "full sweep every ceil(num_pages / K) cycles)")
     args = ap.parse_args()
+    compile_cache.enable()
     if sum((args.faults, args.updates, args.mesh_faults, args.scrub)) > 1:
         ap.error("--faults, --updates, --mesh-faults, and --scrub are "
                  "mutually exclusive sections")

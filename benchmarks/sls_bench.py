@@ -113,6 +113,7 @@ import numpy as np  # noqa: E402
 
 from repro.core import sls as sls_ops  # noqa: E402
 from repro.core.pifs import engine_for_tables  # noqa: E402
+from repro.launch import compile_cache  # noqa: E402
 from repro.data.traces import TraceConfig, TraceGenerator  # noqa: E402
 from repro.distributed.sharding import make_mesh  # noqa: E402
 
@@ -709,6 +710,7 @@ def main() -> None:
                          "lookup->interaction->top-MLP step timing on both "
                          "meshes")
     args = ap.parse_args()
+    compile_cache.enable()
 
     mesh = make_mesh((2, 4), ("data", "model"))
     events = CompileEventCounter()

@@ -27,7 +27,7 @@ as uint32) followed by the page scale's fp32 bit pattern:
     s1 = (sum_i lane_i            + scale_bits)           mod 2^32
     s2 = (sum_i lane_i * (i + 1)  + scale_bits * (N + 1)) mod 2^32
 
-with ``N = page_size * dim`` lanes, stored host-side as the uint64
+with ``N = page_size * store_dim`` lanes, stored host-side as the uint64
 ``(s2 << 32) | s1``.  The position-weighted ``s2`` term makes swapped or
 shifted rows detectable, not just changed sums.  All arithmetic is exact
 integer wraparound, so the numpy fold is *guaranteed* bit-identical to the
@@ -57,7 +57,7 @@ from repro.core.paging import HOT_SHARD
 def page_checksum_host(rows: np.ndarray, scale: float) -> int:
     """Numpy twin of the device per-page checksum (bit-identical).
 
-    ``rows``: the page's (page_size, dim) content in its native dtype
+    ``rows``: the page's (page_size, store_dim) stored content, native dtype
     (int8 codes or float32 values); ``scale``: the page's carried fp32
     scale.  Returns the uint64 ``(s2 << 32) | s1`` as a Python int.
     """

@@ -24,6 +24,8 @@ import jax
 import jax.numpy as jnp
 import numpy as np
 
+from repro.kernels.sls import LANES
+
 HOT_SHARD = -1  # sentinel in page_to_shard
 
 STORAGE_FORMATS = ("fp32", "int8")  # cold-tier storage format knob
@@ -52,11 +54,23 @@ class PagingConfig:
         return 1 if self.storage == "int8" else self.itemsize
 
     @property
+    def store_dim(self) -> int:
+        """Stored row width: ``dim`` rounded up to the TPU's 128-lane tile.
+        The chip lays out and DMAs rows only in whole lane tiles, so the
+        store is padded once, when it is built, instead of being copied to
+        a padded layout inside every program that reads it.  The pad lanes
+        hold zeros and never leave the engine."""
+        return -(-self.dim // LANES) * LANES
+
+    @property
     def page_size(self) -> int:
-        """Rows per page (>=1).  ``page_bytes`` means *stored* bytes, so an
-        int8 cold tier packs ``itemsize/cold_itemsize``x the rows per page;
-        hot pages hold the same rows at fp32 width (they are larger in
-        DRAM — the hot tier is small by construction)."""
+        """Rows per page (>=1).  ``page_bytes`` counts the bytes of the
+        logical ``dim`` lanes in the cold tier's storage format, so an int8
+        cold tier packs ``itemsize/cold_itemsize``x the rows per page; hot
+        pages hold the same rows at fp32 width (they are larger in DRAM —
+        the hot tier is small by construction).  A page occupies
+        ``store_dim/dim``x ``page_bytes`` of device memory when ``dim`` is
+        not lane-aligned."""
         return max(1, self.page_bytes // (self.dim * self.cold_itemsize))
 
     @property

@@ -75,6 +75,24 @@ class EngineState:
         return PageTable(self.page_to_shard, self.page_to_slot)
 
 
+def _take_two_tier(cold: jax.Array, hot: jax.Array, src: jax.Array
+                   ) -> jax.Array:
+    """Rows of the concatenation ``[cold | hot]`` at positions ``src``,
+    without a second store-sized buffer: one gather from ``cold``, then the
+    few rows sourced from ``hot`` (at most one per hot row — each page has
+    one destination) are patched in place.  At the paper's table sizes a
+    full-store concat, or a select between two full-size gathers, does not
+    fit one chip's HBM beside the old and new store."""
+    C = cold.shape[0]
+    from_hot = src >= C
+    out = jnp.take(cold, jnp.where(from_hot, 0, src), axis=0)
+    n = min(hot.shape[0], src.shape[0])
+    pos = jnp.nonzero(from_hot, size=n, fill_value=src.shape[0])[0]
+    hsrc = jnp.take(src, jnp.minimum(pos, src.shape[0] - 1)) - C
+    rows = jnp.take(hot, jnp.clip(hsrc, 0, hot.shape[0] - 1), axis=0)
+    return out.at[pos].set(rows.astype(out.dtype), mode="drop")
+
+
 class PIFSEmbeddingEngine:
     """Sharded multi-table embedding with paged placement + hot tier."""
 
@@ -158,9 +176,9 @@ class PIFSEmbeddingEngine:
     def state_shapes(self) -> EngineState:
         c = self.cfg
         return EngineState(
-            cold=jax.ShapeDtypeStruct((c.cold_rows_total, c.dim),
+            cold=jax.ShapeDtypeStruct((c.cold_rows_total, c.store_dim),
                                       self.cold_dtype),
-            hot=jax.ShapeDtypeStruct((c.hot_rows, c.dim), self.dtype),
+            hot=jax.ShapeDtypeStruct((c.hot_rows, c.store_dim), self.dtype),
             page_scales=jax.ShapeDtypeStruct((c.num_pages,), jnp.float32),
             page_to_shard=jax.ShapeDtypeStruct((c.num_pages,), jnp.int32),
             page_to_slot=jax.ShapeDtypeStruct((c.num_pages,), jnp.int32),
@@ -172,13 +190,83 @@ class PIFSEmbeddingEngine:
                             self.state_pspecs(),
                             is_leaf=lambda x: isinstance(x, P))
 
+    def commit(self, state: EngineState) -> EngineState:
+        """Place every leaf on :meth:`state_shardings`.  jit keys its
+        cache on input shardings, so every method that produces an
+        EngineState returns it through here: a leaf left uncommitted or on
+        another sharding would retrace every compiled plan after each
+        maintenance step.  A leaf already placed there is not copied."""
+        return jax.device_put(state, self.state_shardings())
+
+    def _pad_lanes(self, rows: jax.Array) -> jax.Array:
+        """(..., dim) -> (..., store_dim), zero pad lanes."""
+        pad = self.cfg.store_dim - rows.shape[-1]
+        if pad == 0:
+            return rows
+        return jnp.pad(rows, [(0, 0)] * (rows.ndim - 1) + [(0, pad)])
+
+    def _logical(self, rows: jax.Array) -> jax.Array:
+        """(..., store_dim) -> (..., dim): drop the pad lanes."""
+        return rows[..., :self.cfg.dim]
+
+    def optimizer_step(self, emb_optimizer, state: EngineState,
+                       grad_cold: jax.Array, grad_hot: jax.Array, opt_state
+                       ) -> Tuple[EngineState, object]:
+        """Apply an embedding optimizer to both tiers over the logical
+        ``dim`` lanes only, so a row-wise statistic never averages in the
+        store's zero pad lanes (which stay zero)."""
+        params = {"cold": self._logical(state.cold),
+                  "hot": self._logical(state.hot)}
+        grads = {"cold": self._logical(grad_cold),
+                 "hot": self._logical(grad_hot)}
+        new, new_opt = emb_optimizer.update(grads, opt_state, params)
+        return dataclasses.replace(
+            state, cold=self._pad_lanes(new["cold"]),
+            hot=self._pad_lanes(new["hot"])), new_opt
+
     # ------------------------------------------------------------------- init
     def init_state(self, key: jax.Array, scale: float = 0.01) -> EngineState:
-        """Random-init tables, initial round-robin interleave placement."""
+        """Random-init tables, initial round-robin interleave placement.
+
+        Equal to ``from_dense(normal(key, (padded_rows, D)) * scale)``, but
+        built by one program with the state's output shardings: the
+        interleave puts page ``k * n_shards + s`` in slot ``k`` of shard
+        ``s``, so drawing the pages as ``(slots, n_shards, page, D)`` and
+        moving the shard axis first is the cold layout, and the counter-
+        based generator gives every row the same value in any draw shape.
+        Each shard draws only its own pages, so the store never exists as
+        one dense array on one device.  Rows are stored ``store_dim`` wide
+        (see ``PagingConfig.store_dim``)."""
         c = self.cfg
+        n, ps, D = c.n_shards, c.page_size, c.dim
+        S = -(-c.num_pages // n)             # interleave slots in use
         table = initial_page_table(c)
-        dense = jax.random.normal(key, (c.padded_rows, c.dim), self.dtype) * scale
-        return self.from_dense(dense, table)
+
+        def build(key):
+            # the barrier keeps XLA from fusing the scale into the
+            # generator's arithmetic (which changes the rounding)
+            pages = jax.lax.optimization_barrier(
+                jax.random.normal(key, (S, n, ps, D), self.dtype)) * scale
+            page_id = jnp.arange(S)[:, None] * n + jnp.arange(n)[None, :]
+            pages = jnp.where((page_id < c.num_pages)[:, :, None, None],
+                              pages, 0).transpose(1, 0, 2, 3)
+            if self.quantized:
+                codes, sc = quant.quantize_pages(pages.reshape(n * S, ps, D))
+                pages = codes.reshape(n, S, ps, D)
+                scales = sc.reshape(n, S).T.reshape(-1)[:c.num_pages]
+            else:
+                scales = jnp.ones((c.num_pages,), jnp.float32)
+            cold = jnp.pad(pages, ((0, 0), (0, c.pages_per_shard - S),
+                                   (0, 0), (0, c.store_dim - D)))
+            return EngineState(
+                cold=cold.reshape(c.cold_rows_total, c.store_dim),
+                hot=jnp.zeros((c.hot_rows, c.store_dim), self.dtype),
+                page_scales=scales,
+                page_to_shard=jnp.asarray(table.page_to_shard, jnp.int32),
+                page_to_slot=jnp.asarray(table.page_to_slot, jnp.int32),
+                counts=jnp.zeros((c.num_pages,), jnp.float32))
+
+        return jax.jit(build, out_shardings=self.state_shardings())(key)
 
     def from_dense(self, dense: jax.Array, table: Optional[PageTable] = None
                    ) -> EngineState:
@@ -199,6 +287,7 @@ class PIFSEmbeddingEngine:
             pad = c.padded_rows - dense.shape[0]
             dense = jnp.concatenate(
                 [dense, jnp.zeros((pad, c.dim), dense.dtype)], axis=0)
+        dense = self._pad_lanes(dense)
         ps = c.page_size
         shard = np.asarray(table.page_to_shard)
         slot = np.asarray(table.page_to_slot)
@@ -211,13 +300,13 @@ class PIFSEmbeddingEngine:
 
         if self.quantized:
             q_pages, scales = quant.quantize_pages(
-                dense.reshape(c.num_pages, ps, c.dim))
-            cold_vals = q_pages.reshape(c.num_pages * ps, c.dim)
+                dense.reshape(c.num_pages, ps, c.store_dim))
+            cold_vals = q_pages.reshape(c.num_pages * ps, c.store_dim)
         else:
             scales = jnp.ones((c.num_pages,), jnp.float32)
             cold_vals = dense
-        cold = jnp.zeros((c.cold_rows_total, c.dim), self.cold_dtype)
-        hot = jnp.zeros((c.hot_rows, c.dim), dense.dtype)
+        cold = jnp.zeros((c.cold_rows_total, c.store_dim), self.cold_dtype)
+        hot = jnp.zeros((c.hot_rows, c.store_dim), dense.dtype)
         if cold_pages.size:
             dst = (cold_dst[cold_pages, None] + row_off).ravel()
             src = (cold_pages[:, None] * ps + row_off).ravel()
@@ -226,11 +315,11 @@ class PIFSEmbeddingEngine:
             dst = (hot_dst[hot_pages, None] + row_off).ravel()
             src = (hot_pages[:, None] * ps + row_off).ravel()
             hot = hot.at[dst].set(dense[src])
-        return EngineState(
+        return self.commit(EngineState(
             cold=cold, hot=hot, page_scales=scales,
             page_to_shard=jnp.asarray(shard, jnp.int32),
             page_to_slot=jnp.asarray(slot, jnp.int32),
-            counts=jnp.zeros((c.num_pages,), jnp.float32))
+            counts=jnp.zeros((c.num_pages,), jnp.float32)))
 
     def to_dense(self, state: EngineState) -> jax.Array:
         """Inverse of from_dense (tests / checkpoints / planner-free export).
@@ -248,7 +337,7 @@ class PIFSEmbeddingEngine:
             cold_rows = quant.dequantize_rows(
                 cold_rows, state.page_scales[row // ps][:, None])
         hot_rows = jnp.take(state.hot, jnp.where(is_hot, local_row, 0), axis=0)
-        return jnp.where(is_hot[:, None], hot_rows, cold_rows)
+        return self._logical(jnp.where(is_hot[:, None], hot_rows, cold_rows))
 
     def export_state(self, state: EngineState
                      ) -> Tuple[jax.Array, jax.Array, jax.Array]:
@@ -276,10 +365,10 @@ class PIFSEmbeddingEngine:
         row = jnp.arange(c.padded_rows)
         shard, local_row, is_hot = locate(c, state.page_table, row)
         cold_pos = shard * c.rows_per_shard + local_row
-        cold_rows = jnp.take(state.cold, jnp.where(is_hot, 0, cold_pos),
-                             axis=0)
-        hot_rows = jnp.take(state.hot, jnp.where(is_hot, local_row, 0),
-                            axis=0)
+        cold_rows = self._logical(
+            jnp.take(state.cold, jnp.where(is_hot, 0, cold_pos), axis=0))
+        hot_rows = self._logical(
+            jnp.take(state.hot, jnp.where(is_hot, local_row, 0), axis=0))
         if self.quantized:
             s = state.page_scales[row // ps][:, None]
             codes = jnp.where(is_hot[:, None],
@@ -314,10 +403,10 @@ class PIFSEmbeddingEngine:
         row_off = np.arange(ps)
         cold_pages = np.nonzero(shard != HOT_SHARD)[0]
         hot_pages = np.nonzero(shard == HOT_SHARD)[0]
-        cold = jnp.zeros((c.cold_rows_total, c.dim), self.cold_dtype)
-        hot = jnp.zeros((c.hot_rows, c.dim), self.dtype)
-        codes = jnp.asarray(codes)
-        values = jnp.asarray(values)
+        cold = jnp.zeros((c.cold_rows_total, c.store_dim), self.cold_dtype)
+        hot = jnp.zeros((c.hot_rows, c.store_dim), self.dtype)
+        codes = self._pad_lanes(jnp.asarray(codes))
+        values = self._pad_lanes(jnp.asarray(values))
         if cold_pages.size:
             dst = (cold_dst[cold_pages, None] + row_off).ravel()
             src = (cold_pages[:, None] * ps + row_off).ravel()
@@ -334,10 +423,10 @@ class PIFSEmbeddingEngine:
             page_to_shard=jnp.asarray(shard, jnp.int32),
             page_to_slot=jnp.asarray(slot, jnp.int32),
             counts=jnp.asarray(counts, jnp.float32))
-        # commit to this engine's placement: the inputs may live on a
-        # different (larger/smaller) mesh — the elastic re-mesh path hands
-        # us arrays computed under the pre-loss mesh's sharding
-        return jax.device_put(state, self.state_shardings())
+        # the inputs may live on a different (larger/smaller) mesh — the
+        # elastic re-mesh path hands us arrays computed under the pre-loss
+        # mesh's sharding
+        return self.commit(state)
 
     # ----------------------------------------------------------------- lookup
     def _check_ids(self, indices) -> None:
@@ -631,7 +720,8 @@ class PIFSEmbeddingEngine:
         is_hot = shard == HOT_SHARD
         scale = scales[page] if self.quantized else None
         return sls_ops.fused_front_end_dense(
-            cold, hot, x, local_row, owned, is_hot, weights=weights,
+            cold, hot, self._pad_lanes(x), local_row, owned, is_hot,
+            weights=weights,
             scales=scale, impl=impl, block_l=block_l, block_b=block_b,
             dedup=dedup, out_dtype=jnp.float32)
 
@@ -658,9 +748,11 @@ class PIFSEmbeddingEngine:
         is_hot = shard == HOT_SHARD
         scale = scales[page] if self.quantized else None
         part_c, part_h = sls_ops.fused_partial_pool_dense(
-            cold, hot, x, local_row, owned, is_hot, weights=weights,
-            scales=scale, impl=impl, block_l=block_l, block_b=block_b,
-            dedup=dedup, out_dtype=jnp.float32)
+            cold, hot, self._pad_lanes(x), local_row, owned, is_hot,
+            weights=weights, scales=scale, impl=impl, block_l=block_l,
+            block_b=block_b, dedup=dedup, out_dtype=jnp.float32)
+        # only the logical lanes cross the fabric
+        part_c, part_h = self._logical(part_c), self._logical(part_h)
         reduced = jax.lax.psum(part_c, axes.tp)
         return sls_ops.fused_resume_dense(reduced, part_h, impl=impl,
                                           block_b=block_b)
@@ -691,11 +783,12 @@ class PIFSEmbeddingEngine:
         B, G, L = indices.shape
         dp = self.axes.dp_size(self.mesh) if dp_shard else 1
         n_entries = max(B // max(dp, 1), 1) * G * L    # per-device entries
+        W = self.cfg.store_dim          # kernels stage rows at stored width
         if fused_blocks is None:
             # split-path dedup: the hot and cold accumulates are separate
-            # kernel invocations, so one (n_entries, D) fp32 row staging is
-            # live at a time
-            staging_bytes = n_entries * self.cfg.dim * 4
+            # kernel invocations, so one (n_entries, store_dim) fp32 row
+            # staging is live at a time
+            staging_bytes = n_entries * W * 4
         else:
             # fused-front-end dedup: one kernel holds BOTH tiers' row
             # stagings plus the two (BB*F, D) per-tier feature accumulators
@@ -705,8 +798,8 @@ class PIFSEmbeddingEngine:
             BB = max(1, min(fused_blocks, b_local))
             while b_local % BB:
                 BB //= 2
-            staging_bytes = (2 * n_entries * self.cfg.dim * 4
-                             + 2 * BB * (G + 1) * self.cfg.dim * 4)
+            staging_bytes = (2 * n_entries * W * 4
+                             + 2 * BB * (G + 1) * W * 4)
         capacity_ok = staging_bytes <= self.dedup_staging_bytes
         counts = state.counts
         if isinstance(counts, jax.core.Tracer):
@@ -930,9 +1023,10 @@ class PIFSEmbeddingEngine:
         # ---- hot tier: replicated, zero-communication ----
         # dedup applies here too: hot hits are local-HBM reads, and under
         # zipfian traffic the hot tier is where duplicates concentrate
-        hot_out = sls_ops.masked_partial_sls_dense(
+        # pooled rows leave without pad lanes
+        hot_out = self._logical(sls_ops.masked_partial_sls_dense(
             hot, local_row, is_hot, wbags, impl=impl,
-            block_l=block_l, dedup=dedup)                       # (nbags, D)
+            block_l=block_l, dedup=dedup))                      # (nbags, D)
 
         if tiers == "hot_only":
             # brown-out rung: serve the replicated hot tier only — cold
@@ -958,8 +1052,8 @@ class PIFSEmbeddingEngine:
             # apply either — the baseline's semantics ship one row per
             # pooling entry, so only the hot tier above dedups in pond mode.
             seg = jnp.repeat(jnp.arange(nbags, dtype=jnp.int32), L)
-            rows = sls_ops.masked_gather_rows(
-                cold, local_row.reshape(-1), owned.reshape(-1))
+            rows = self._logical(sls_ops.masked_gather_rows(
+                cold, local_row.reshape(-1), owned.reshape(-1)))
             if self.quantized:
                 # dequant after the (int8) gather, before rows hit the wire:
                 # pond still ships fp32 rows (the baseline's semantics), the
@@ -983,11 +1077,11 @@ class PIFSEmbeddingEngine:
             return out.reshape(b, G, -1)
 
         # pifs / beacon: partial SLS near the data, pooled partials only
-        cold_part = sls_ops.masked_partial_sls_dense(
+        cold_part = self._logical(sls_ops.masked_partial_sls_dense(
             cold, local_row, owned, wbags, impl=impl,
             block_l=block_l, scales=scale_be,
             out_dtype=jnp.float32 if self.quantized else None,
-            dedup=dedup)                                         # (nbags, D)
+            dedup=dedup))                                        # (nbags, D)
         if combine == "psum":
             cold_sum = jax.lax.psum(cold_part, tp)
             return (cold_sum + hot_out).reshape(b, G, -1)
@@ -1041,7 +1135,7 @@ class PIFSEmbeddingEngine:
         if not isinstance(new_counts, jax.core.Tracer):
             # host copy for dedup='auto' plan resolution under outer traces
             self._host_counts = np.asarray(new_counts)
-        return dataclasses.replace(state, counts=new_counts)
+        return self.commit(dataclasses.replace(state, counts=new_counts))
 
     # ------------------------------------------------------- plan + migration
     def plan_and_migrate(self, state: EngineState) -> Tuple[EngineState, dict]:
@@ -1091,9 +1185,8 @@ class PIFSEmbeddingEngine:
 
                 def block(cold, hot, cs, hs):
                     full = jax.lax.all_gather(cold, tp, axis=0, tiled=True)
-                    comb = jnp.concatenate([full, hot], axis=0)
-                    return (jnp.take(comb, cs, axis=0),
-                            jnp.take(comb, hs, axis=0))
+                    return (_take_two_tier(full, hot, cs),
+                            _take_two_tier(full, hot, hs))
 
                 self._migrate_plan = jax.jit(shard_map(
                     block, mesh=self.mesh,
@@ -1104,11 +1197,11 @@ class PIFSEmbeddingEngine:
                 state.cold, state.hot,
                 jnp.asarray(cold_src.astype(np.int32)),
                 jnp.asarray(hot_src.astype(np.int32)))
-        return EngineState(
+        return self.commit(EngineState(
             cold=new_cold, hot=new_hot, page_scales=state.page_scales,
             page_to_shard=jnp.asarray(np.asarray(new_table.page_to_shard), jnp.int32),
             page_to_slot=jnp.asarray(np.asarray(new_table.page_to_slot), jnp.int32),
-            counts=state.counts * count_decay)  # decay after replan (EWMA)
+            counts=state.counts * count_decay))  # decay after replan (EWMA)
 
     def _migrate_quantized(self, state: EngineState, new_table: PageTable,
                            cold_src: np.ndarray, hot_src: np.ndarray):
@@ -1152,8 +1245,7 @@ class PIFSEmbeddingEngine:
                 # gather).  A previously promoted page holds exactly
                 # q * scale, so round(q * scale / scale) == q: lossless.
                 hot_q = quant.quantize_rows(hot, scales[old_hot_page][:, None])
-                new_cold = jnp.take(jnp.concatenate([full, hot_q], axis=0),
-                                    cs, axis=0)
+                new_cold = _take_two_tier(full, hot_q, cs)
                 # promotions: dequantize cold codes into the fp32 hot tier
                 promoted = quant.dequantize_rows(
                     jnp.take(full, hs_cold, axis=0),
@@ -1220,8 +1312,9 @@ class PIFSEmbeddingEngine:
         self._plan_calls += 1
         new_cold, new_hot = plan(state.cold, state.hot, state.page_scales,
                                  state.page_to_shard, state.page_to_slot,
-                                 rows, deltas)
-        return dataclasses.replace(state, cold=new_cold, hot=new_hot)
+                                 rows, self._pad_lanes(deltas))
+        return self.commit(dataclasses.replace(state, cold=new_cold,
+                                               hot=new_hot))
 
     def _build_update_plan(self):
         """shard_map + jit closure for one apply_deltas signature."""
@@ -1308,7 +1401,7 @@ class PIFSEmbeddingEngine:
         self._plan_calls += 1
         new_hot = plan(state.hot, state.page_scales, state.page_to_shard,
                        state.page_to_slot, pages)
-        return dataclasses.replace(state, hot=new_hot)
+        return self.commit(dataclasses.replace(state, hot=new_hot))
 
     def _build_requant_plan(self):
         c = self.cfg
@@ -1456,10 +1549,10 @@ class PIFSEmbeddingEngine:
         new_cold, new_hot, new_scales = plan(
             state.cold, state.hot, state.page_scales, state.page_to_shard,
             state.page_to_slot, pg,
-            jnp.asarray(cold_rows, self.cold_dtype),
-            jnp.asarray(hot_rows, jnp.float32), sc)
-        return dataclasses.replace(state, cold=new_cold, hot=new_hot,
-                                   page_scales=new_scales)
+            self._pad_lanes(jnp.asarray(cold_rows, self.cold_dtype)),
+            self._pad_lanes(jnp.asarray(hot_rows, jnp.float32)), sc)
+        return self.commit(dataclasses.replace(
+            state, cold=new_cold, hot=new_hot, page_scales=new_scales))
 
     def _build_page_write_plan(self):
         axes, mesh = self.axes, self.mesh

@@ -20,6 +20,7 @@ granular migration of section IV-B4).
 from __future__ import annotations
 
 import dataclasses
+import heapq
 from typing import Optional, Tuple
 
 import numpy as np
@@ -73,19 +74,28 @@ def plan(cfg: PagingConfig, table: PageTable, counts: np.ndarray,
     want_hot = set(order[: cfg.hot_pages].tolist())
     resident_hot = set(np.nonzero(old_shard == HOT_SHARD)[0].tolist())
     if pcfg.sticky and resident_hot:
-        # evict a resident page only if some challenger beats it by margin
-        floor = min(counts[p] for p in resident_hot)
+        # evict a resident page only if some challenger beats it by
+        # margin.  The coldest member is the victim (a heap: the hot set
+        # holds ~5% of the pages, so a linear scan per challenger is
+        # quadratic at table scale); challengers come hottest first and
+        # the coldest member only warms, so the first challenger that
+        # loses ends the scan.
         new_hot = set(resident_hot)
-        challengers = [p for p in order[: 4 * cfg.hot_pages]
+        heap = [(counts[p], p) for p in new_hot]
+        heapq.heapify(heap)
+        challengers = [int(p) for p in order[: 4 * cfg.hot_pages]
                        if p not in resident_hot]
         for c in challengers:
             if len(new_hot) < cfg.hot_pages:
-                new_hot.add(int(c))
+                new_hot.add(c)
+                heapq.heappush(heap, (counts[c], c))
                 continue
-            victim = min((p for p in new_hot), key=lambda p: counts[p])
-            if counts[c] > counts[victim] * (1.0 + pcfg.cold_age_threshold):
-                new_hot.discard(victim)
-                new_hot.add(int(c))
+            floor, victim = heap[0]
+            if not counts[c] > floor * (1.0 + pcfg.cold_age_threshold):
+                break
+            heapq.heapreplace(heap, (counts[c], c))
+            new_hot.discard(victim)
+            new_hot.add(c)
         hot_set = new_hot
     else:
         hot_set = want_hot
@@ -104,17 +114,14 @@ def plan(cfg: PagingConfig, table: PageTable, counts: np.ndarray,
 
     sticky_kept = 0
     if pcfg.sticky and not needs_migration(cfg, table, counts, pcfg):
-        # no node is warm: keep every already-cold page in place
-        for p in cold_pages:
-            s = old_shard[p]
-            if s != HOT_SHARD:
-                new_shard[p] = s
-                # keep slot if unique; slots stay unique because assignment
-                # within a shard is unchanged
-                new_slot[p] = old_slot[p]
-                loads[s] += counts[p]
-                fill[s] = max(fill[s], old_slot[p] + 1)
-                sticky_kept += 1
+        # no node is warm: keep every already-cold page in place (slots
+        # stay unique because assignment within a shard is unchanged)
+        keep = cold_pages[old_shard[cold_pages] != HOT_SHARD]
+        new_shard[keep] = old_shard[keep]
+        new_slot[keep] = old_slot[keep]
+        np.add.at(loads, old_shard[keep], counts[keep])
+        np.maximum.at(fill, old_shard[keep], old_slot[keep] + 1)
+        sticky_kept = int(keep.size)
         unplaced = cold_pages[new_shard[cold_pages] == HOT_SHARD]
     else:
         unplaced = cold_pages

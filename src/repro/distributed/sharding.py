@@ -10,7 +10,7 @@ Logical axes:
 from __future__ import annotations
 
 import dataclasses
-from typing import Optional, Sequence, Tuple, Union
+from typing import Sequence, Tuple
 
 import jax
 import numpy as np
@@ -38,36 +38,20 @@ class MeshAxes:
         return self.dp_size(mesh) * self.tp_size(mesh)
 
 
-def make_mesh(shape: Sequence[int], names: Sequence[str]) -> Mesh:
-    """jax.make_mesh with explicit Auto axis types where the API has them.
-
-    jax >= 0.5 wants ``axis_types`` spelled out to stay on Auto semantics;
-    jax 0.4.x predates ``jax.sharding.AxisType`` (everything is Auto), so the
-    kwarg is only passed when it exists.
-    """
-    kwargs = {}
-    if hasattr(jax.sharding, "AxisType"):
-        kwargs["axis_types"] = (jax.sharding.AxisType.Auto,) * len(names)
-    return jax.make_mesh(tuple(shape), tuple(names), **kwargs)
-
-
-# ---------------------------------------------------------------------------
-# shard_map compat: jax >= 0.5 exposes jax.shard_map(..., check_vma=...);
-# jax 0.4.x has jax.experimental.shard_map.shard_map(..., check_rep=...).
-# All repro code routes through this wrapper so both spellings work.
-# ---------------------------------------------------------------------------
-if hasattr(jax, "shard_map"):
-    _shard_map_impl = jax.shard_map
-    _shard_map_check_kwarg = "check_vma"
-else:  # jax 0.4.x
-    from jax.experimental.shard_map import shard_map as _shard_map_impl
-    _shard_map_check_kwarg = "check_rep"
+def make_mesh(shape: Sequence[int], names: Sequence[str],
+              devices=None) -> Mesh:
+    """``jax.make_mesh`` with Auto axis types: the engine's shard_map blocks
+    and the models' ``with_sharding_constraint`` calls assume Auto
+    semantics, which jax.make_mesh no longer defaults to."""
+    kwargs = {} if devices is None else {"devices": devices}
+    return jax.make_mesh(tuple(shape), tuple(names),
+                         axis_types=(jax.sharding.AxisType.Auto,) * len(names),
+                         **kwargs)
 
 
 def shard_map(f, mesh: Mesh, in_specs, out_specs, check_vma: bool = False):
-    return _shard_map_impl(f, mesh=mesh, in_specs=in_specs,
-                           out_specs=out_specs,
-                           **{_shard_map_check_kwarg: check_vma})
+    return jax.shard_map(f, mesh=mesh, in_specs=in_specs,
+                         out_specs=out_specs, check_vma=check_vma)
 
 
 def axes_for(mesh: Mesh) -> MeshAxes:

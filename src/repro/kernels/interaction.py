@@ -8,29 +8,19 @@ Blocking: grid over batch blocks; one (BB, F, D) activation block in VMEM per
 step.  F, D are small (F <= ~40 fields, D <= 128), so a batch block of 128
 keeps the MXU busy with a (F, D) x (D, F) matmul per sample batch while the
 working set stays ~ BB*F*D*4 = 128*32*128*4 = 2 MB << VMEM.  The triangle
-pack is a static gather on the (BB, F*F) reshape, fused into the same kernel
-to avoid a round trip of the (B, F, F) tensor to HBM — that round trip is
-2x the kernel's entire output traffic.
+pack is a concatenation of static slices of the (BB, F, F) product, fused
+into the same kernel to avoid a round trip of the (B, F, F) tensor to HBM —
+that round trip is 2x the kernel's entire output traffic.
 """
 from __future__ import annotations
 
 import functools
 
 import jax
-import jax.numpy as jnp
-import numpy as np
 from jax.experimental import pallas as pl
-from jax.experimental.pallas import tpu as pltpu
 
-
-def _interaction_kernel(tri_ref, x_ref, out_ref):
-    x = x_ref[...]                                      # (BB, F, D)
-    z = jax.lax.dot_general(
-        x, x, (((2,), (2,)), ((0,), (0,))),
-        preferred_element_type=out_ref.dtype)           # (BB, F, F)
-    bb, F, _ = z.shape
-    flat = z.reshape(bb, F * F)
-    out_ref[...] = jnp.take(flat, tri_ref[...], axis=1)
+from repro.kernels.sls import (_bag_blocks, _default_interpret, _interact,
+                               _pad_rows)
 
 
 @functools.partial(jax.jit, static_argnames=("self_interaction", "block_b",
@@ -38,31 +28,26 @@ def _interaction_kernel(tri_ref, x_ref, out_ref):
 def dot_interaction_pallas(feats: jax.Array, self_interaction: bool = False,
                            block_b: int = 128,
                            interpret: bool | None = None) -> jax.Array:
-    """feats: (B, F, D) -> (B, P) packed triangle. B must divide block_b
-    (caller pads); P = F*(F-1)/2 (+F with self_interaction).
+    """feats: (B, F, D) -> (B, P) packed triangle, P = F*(F-1)/2 (+F with
+    self_interaction).  The batch is padded to whole ``block_b`` tiles.
 
     ``interpret=None`` (the default) detects the backend once at trace
-    time: compiled on TPU, interpreter elsewhere.  The old default of
-    ``True`` made real-TPU callers that never threaded the knob silently
-    run the interpreter; pass an explicit bool to override detection."""
+    time: compiled on TPU, interpreter elsewhere; pass an explicit bool to
+    override detection."""
     if interpret is None:
-        interpret = jax.default_backend() != "tpu"
+        interpret = _default_interpret()
     B, F, D = feats.shape
-    block_b = min(block_b, B)
-    if B % block_b:
-        raise ValueError(f"B={B} not divisible by block_b={block_b}")
-    i, j = np.tril_indices(F, k=0 if self_interaction else -1)
-    tri = jnp.asarray(i * F + j, jnp.int32)
-    P = tri.shape[0]
-    # tri rides in SMEM via scalar prefetch (static pack permutation)
-    grid_spec = pltpu.PrefetchScalarGridSpec(
-        num_scalar_prefetch=1,
-        grid=(B // block_b,),
-        in_specs=[pl.BlockSpec((block_b, F, D), lambda b, tri_ref: (b, 0, 0))],
-        out_specs=pl.BlockSpec((block_b, P), lambda b, tri_ref: (b, 0)),
-    )
-    return pl.pallas_call(
-        _interaction_kernel, grid_spec=grid_spec,
-        out_shape=jax.ShapeDtypeStruct((B, P), feats.dtype),
+    P = F * (F + 1) // 2 if self_interaction else F * (F - 1) // 2
+    Bp, BB = _bag_blocks(B, block_b)
+
+    def kernel(x_ref, out_ref):
+        out_ref[...] = _interact(x_ref[...], out_ref.dtype, self_interaction)
+
+    out = pl.pallas_call(
+        kernel, grid=(Bp // BB,),
+        in_specs=[pl.BlockSpec((BB, F, D), lambda b: (b, 0, 0))],
+        out_specs=pl.BlockSpec((BB, P), lambda b: (b, 0)),
+        out_shape=jax.ShapeDtypeStruct((Bp, P), feats.dtype),
         interpret=interpret,
-    )(tri, feats)
+    )(_pad_rows(feats, Bp))
+    return out[:B]

@@ -5,12 +5,10 @@ runs as Python/jnp on CPU); on TPU set ``interpret=False`` (the default picks
 by backend).  ``impl='jnp'`` falls back to the oracle — models use that path
 for fast CPU smoke tests, while tests sweep the pallas path against ref.
 
-Lane alignment: TPU tiles are (sublane, 128); embedding dims that are not a
-multiple of 128 are zero-padded here (table columns + output slice) before the
-kernel sees them, so the kernel itself always works on lane-aligned rows.
-Padding defaults to on for compiled TPU execution and off in interpret mode
-(where alignment buys nothing); production deployments should store tables
-pre-padded to avoid the per-call pad (see EXPERIMENTS.md §Perf).
+Lane alignment: on the chip the SLS and fused kernels DMA table rows in
+whole 128-lane tiles, so their tables (and ``x``) must be lane-aligned
+there.  The engine stores its rows padded once (``PagingConfig.store_dim``);
+these wrappers never pad, and a misaligned compiled call is refused.
 """
 from __future__ import annotations
 
@@ -21,84 +19,59 @@ import jax.numpy as jnp
 
 from repro.kernels import ref
 from repro.kernels.interaction import dot_interaction_pallas
-from repro.kernels.sls import (fused_front_end_dedup_pallas,
+from repro.kernels.sls import (_default_interpret,
+                               fused_front_end_dedup_pallas,
                                fused_front_end_pallas,
                                fused_partial_pool_dedup_pallas,
                                fused_partial_pool_pallas, fused_resume_pallas,
                                masked_sls_dedup_pallas, masked_sls_pallas,
                                sls_pallas)
 
-LANES = 128
-
-
-def _default_interpret() -> bool:
-    return jax.default_backend() != "tpu"
-
-
-def pad_to_lanes(table: jax.Array, pad_lanes: bool) -> jax.Array:
-    """Zero-pad the minor (D) dim up to the 128-lane boundary."""
-    D = table.shape[-1]
-    if not pad_lanes or D % LANES == 0:
-        return table
-    return jnp.pad(table, ((0, 0), (0, LANES - D % LANES)))
-
 
 def sls(table: jax.Array, indices: jax.Array,
         weights: Optional[jax.Array] = None, out_dtype=jnp.float32,
         impl: str = "pallas", interpret: Optional[bool] = None,
-        block_l: int = 8, pad_lanes: Optional[bool] = None) -> jax.Array:
+        block_l: int = 8) -> jax.Array:
     """Pooled embedding lookup: indices (B, L) -> (B, D)."""
     if impl == "jnp":
         return ref.sls_ref(table, indices, weights, out_dtype)
     if interpret is None:
         interpret = _default_interpret()
-    if pad_lanes is None:
-        pad_lanes = not interpret
-    D = table.shape[-1]
-    out = sls_pallas(pad_to_lanes(table, pad_lanes), indices, weights,
-                     out_dtype=out_dtype, interpret=interpret,
-                     block_l=block_l)
-    return out[:, :D]
+    return sls_pallas(table, indices, weights, out_dtype=out_dtype,
+                      interpret=interpret, block_l=block_l)
 
 
 def masked_sls(table: jax.Array, indices: jax.Array, owned: jax.Array,
                weights: Optional[jax.Array] = None, out_dtype=jnp.float32,
                impl: str = "pallas", interpret: Optional[bool] = None,
-               block_l: int = 8, pad_lanes: Optional[bool] = None,
-               scales: Optional[jax.Array] = None) -> jax.Array:
+               block_l: int = 8, scales: Optional[jax.Array] = None) -> jax.Array:
     """Masked partial SLS (the PIFS per-shard operator): (B, L) -> (B, D).
 
     ``scales`` (B, L, optional) dequantizes a quantized (int8) ``table``
     per gathered row inside the kernel (fused dequant; see kernels/sls.py).
-    Lane padding only touches the table's D axis, so scales are unaffected.
     """
     if impl == "jnp":
         return ref.masked_sls_ref(table, indices, owned, weights, out_dtype,
                                   scales=scales)
     if interpret is None:
         interpret = _default_interpret()
-    if pad_lanes is None:
-        pad_lanes = not interpret
-    D = table.shape[-1]
-    out = masked_sls_pallas(pad_to_lanes(table, pad_lanes), indices, owned,
-                            weights, scales, out_dtype=out_dtype,
-                            interpret=interpret, block_l=block_l)
-    return out[:, :D]
+    return masked_sls_pallas(table, indices, owned, weights, scales,
+                             out_dtype=out_dtype, interpret=interpret,
+                             block_l=block_l)
 
 
 def masked_sls_dedup(table: jax.Array, plan, owned: jax.Array,
                      weights: Optional[jax.Array] = None,
                      out_dtype=jnp.float32, impl: str = "pallas",
-                     interpret: Optional[bool] = None, block_l: int = 8,
-                     pad_lanes: Optional[bool] = None) -> jax.Array:
+                     interpret: Optional[bool] = None, block_l: int = 8
+                     ) -> jax.Array:
     """Gather-once dedup'd masked partial SLS: each unique owned row is
     DMA'd (and dequantized) exactly once into VMEM staging, then the
     bag-tiled accumulate reads through the plan's slot indirection.
 
     ``plan`` is a ``core/sls.DedupPlan`` (unique_rows/slots/n_slots/
-    unique_scales); build it with ``core/sls.dedup_plan``.  Lane padding
-    only touches the table's D axis — the plan arrays are index-space and
-    unaffected.  Bit-for-bit equal to :func:`masked_sls` in fp32 (oracle:
+    unique_scales); build it with ``core/sls.dedup_plan``.  Bit-for-bit
+    equal to :func:`masked_sls` in fp32 (oracle:
     ``ref.masked_sls_dedup_ref``).
     """
     if impl == "jnp":
@@ -107,14 +80,10 @@ def masked_sls_dedup(table: jax.Array, plan, owned: jax.Array,
             unique_scales=plan.unique_scales, out_dtype=out_dtype)
     if interpret is None:
         interpret = _default_interpret()
-    if pad_lanes is None:
-        pad_lanes = not interpret
-    D = table.shape[-1]
-    out = masked_sls_dedup_pallas(
-        pad_to_lanes(table, pad_lanes), plan.unique_rows, plan.slots,
-        owned, plan.n_slots, weights, plan.unique_scales,
-        out_dtype=out_dtype, interpret=interpret, block_l=block_l)
-    return out[:, :D]
+    return masked_sls_dedup_pallas(
+        table, plan.unique_rows, plan.slots, owned, plan.n_slots, weights,
+        plan.unique_scales, out_dtype=out_dtype, interpret=interpret,
+        block_l=block_l)
 
 
 def dot_interaction(feats: jax.Array, self_interaction: bool = False,
@@ -125,11 +94,8 @@ def dot_interaction(feats: jax.Array, self_interaction: bool = False,
     threads an explicit override through to ``dot_interaction_pallas``."""
     if impl == "jnp":
         return ref.dot_interaction_ref(feats, self_interaction)
-    B = feats.shape[0]
-    while B % block_b:
-        block_b //= 2
-    return dot_interaction_pallas(feats, self_interaction,
-                                  block_b=max(block_b, 1), interpret=interpret)
+    return dot_interaction_pallas(feats, self_interaction, block_b=block_b,
+                                  interpret=interpret)
 
 
 def fused_front_end(cold: jax.Array, hot: jax.Array, x: jax.Array,
@@ -138,8 +104,7 @@ def fused_front_end(cold: jax.Array, hot: jax.Array, x: jax.Array,
                     scales: Optional[jax.Array] = None,
                     dedup_plans=None, out_dtype=jnp.float32,
                     impl: str = "pallas", interpret: Optional[bool] = None,
-                    block_l: int = 8, block_b: int = 32,
-                    pad_lanes: Optional[bool] = None) -> jax.Array:
+                    block_l: int = 8, block_b: int = 32) -> jax.Array:
     """Fused DLRM front end: masked two-tier SLS -> dot-interaction in one
     kernel — the pooled (B, F, D) features tensor never exists in HBM.
 
@@ -147,9 +112,8 @@ def fused_front_end(cold: jax.Array, hot: jax.Array, x: jax.Array,
     masks, ``x`` (B, D) the bottom-MLP output.  ``dedup_plans`` is an
     optional ``(cold_plan, hot_plan)`` pair of ``core/sls.DedupPlan``s
     (slots reshaped (B, G, L) by the caller) selecting the gather-once
-    kernel variant.  Lane padding touches only the D axis of the three
-    dense operands; the (B, P) output is D-free, so no slice-back is
-    needed (zero lanes add exact +0 terms to every pairwise dot).
+    kernel variant.  The (B, P) output is D-free: zero pad lanes in the
+    three dense operands add exact +0 terms to every pairwise dot.
     Bit-for-bit equal to the split pipeline in fp32 (oracle:
     ``ref.fused_front_end_ref``).
     """
@@ -162,11 +126,6 @@ def fused_front_end(cold: jax.Array, hot: jax.Array, x: jax.Array,
                                        weights, scales, out_dtype)
     if interpret is None:
         interpret = _default_interpret()
-    if pad_lanes is None:
-        pad_lanes = not interpret
-    cold = pad_to_lanes(cold, pad_lanes)
-    hot = pad_to_lanes(hot, pad_lanes)
-    x = pad_to_lanes(x, pad_lanes)
     if dedup_plans is not None:
         cp, hp = dedup_plans
         return fused_front_end_dedup_pallas(
@@ -186,14 +145,12 @@ def fused_partial_pool(cold: jax.Array, hot: jax.Array, x: jax.Array,
                        scales: Optional[jax.Array] = None,
                        dedup_plans=None, out_dtype=jnp.float32,
                        impl: str = "pallas", interpret: Optional[bool] = None,
-                       block_l: int = 8, block_b: int = 32,
-                       pad_lanes: Optional[bool] = None):
+                       block_l: int = 8, block_b: int = 32):
     """Phases 1-2 of :func:`fused_front_end`, stopped at the phase-2/3 seam:
     returns the per-tier partial feature tiles ``(B, F, D)`` — cold (row 0
     zero, the tile a tp dispatch psums across shards) and hot (``x`` in
     row 0; replicated, never reduced).  ``fused_resume`` finishes the
-    interaction on the reduced tile.  Lane padding is sliced back off the
-    tiles so the collective ships exactly ``B*F*D`` elements.  Oracle:
+    interaction on the reduced tile.  Oracle:
     ``ref.fused_partial_pool_ref``.
     """
     if impl == "jnp":
@@ -203,46 +160,30 @@ def fused_partial_pool(cold: jax.Array, hot: jax.Array, x: jax.Array,
                                           weights, scales, out_dtype)
     if interpret is None:
         interpret = _default_interpret()
-    if pad_lanes is None:
-        pad_lanes = not interpret
-    D = cold.shape[-1]
-    cold = pad_to_lanes(cold, pad_lanes)
-    hot = pad_to_lanes(hot, pad_lanes)
-    x = pad_to_lanes(x, pad_lanes)
     if dedup_plans is not None:
         cp, hp = dedup_plans
-        part_c, part_h = fused_partial_pool_dedup_pallas(
+        return fused_partial_pool_dedup_pallas(
             cold, hot, x, cp.unique_rows, cp.slots, cp.n_slots,
             hp.unique_rows, hp.slots, hp.n_slots, owned, is_hot,
             weights, cp.unique_scales, out_dtype=out_dtype,
             interpret=interpret, block_l=block_l, block_b=block_b)
-    else:
-        part_c, part_h = fused_partial_pool_pallas(
-            cold, hot, x, rows, owned, is_hot, weights, scales,
-            out_dtype=out_dtype, interpret=interpret, block_l=block_l,
-            block_b=block_b)
-    return part_c[:, :, :D], part_h[:, :, :D]
+    return fused_partial_pool_pallas(
+        cold, hot, x, rows, owned, is_hot, weights, scales,
+        out_dtype=out_dtype, interpret=interpret, block_l=block_l,
+        block_b=block_b)
 
 
 def fused_resume(part_c: jax.Array, part_h: jax.Array,
                  out_dtype=jnp.float32, impl: str = "pallas",
-                 interpret: Optional[bool] = None, block_b: int = 32,
-                 pad_lanes: Optional[bool] = None) -> jax.Array:
+                 interpret: Optional[bool] = None, block_b: int = 32
+                 ) -> jax.Array:
     """Phase 3 of the fused front end on the psum-reduced ``(B, F, D)``
     tiles: cold/hot add, dot-interaction, packed lower triangle ``(B, P)``.
-    Lane padding adds exact-zero columns to both tiles (zero lanes
-    contribute +0 to every pairwise dot — no slice-back needed on the
-    D-free output).  Oracle: ``ref.fused_resume_ref``.
+    Oracle: ``ref.fused_resume_ref``.
     """
     if impl == "jnp":
         return ref.fused_resume_ref(part_c, part_h)
     if interpret is None:
         interpret = _default_interpret()
-    if pad_lanes is None:
-        pad_lanes = not interpret
-    if pad_lanes and part_c.shape[-1] % LANES:
-        pad = LANES - part_c.shape[-1] % LANES
-        part_c = jnp.pad(part_c, ((0, 0), (0, 0), (0, pad)))
-        part_h = jnp.pad(part_h, ((0, 0), (0, 0), (0, pad)))
     return fused_resume_pallas(part_c, part_h, out_dtype=out_dtype,
                                interpret=interpret, block_b=block_b)

@@ -4,31 +4,37 @@ TPU-native rethink of the paper's fabric-switch datapath (not a CUDA port):
 
   * The embedding table stays in HBM ("CXL memory pool") and is *not* streamed
     by the automatic Pallas pipeline: each grid step manually DMAs the rows it
-    needs into a double-buffered VMEM scratch, so the *next* row's DMA overlaps
-    the current accumulate — the hardware double-buffer plays the role of the
-    paper's swap-register / out-of-order engine: row arrival order never stalls
-    the accumulator.
-  * Indices (and optional ownership mask / weights) ride in SMEM via scalar
-    prefetch — the analogue of the instruction-ingress registry: the index
-    stream must be resident before the table DMAs it drives can be issued
-    (PrefetchScalarGridSpec).
-  * The accumulator lives in VMEM, written back once per bag (revisiting: the
-    out block index depends only on the bag id, so Pallas keeps it resident
-    across the inner tile steps — the Accumulation Configuration Register).
+    needs into a double-buffered VMEM landing pad, so the *next* row's DMA
+    overlaps the current accumulate — the hardware double-buffer plays the
+    role of the paper's swap-register / out-of-order engine: row arrival
+    order never stalls the accumulator.
+  * Each step's slice of the index stream (rows, per-entry factors, dequant
+    scales) rides in SMEM as an auto-pipelined ``(BB, L)`` block — the
+    analogue of the instruction-ingress registry: the indices must be
+    resident before the table DMAs they drive can be issued.  Blocking the
+    stream per step (rather than scalar-prefetching the whole batch) keeps
+    SMEM use independent of the batch: a serve_p99 batch of 512 samples x
+    8 tables x pooling 8 is 32K entries per operand, past the 1 MiB SMEM.
+  * The accumulator lives in VMEM, written back once per bag block
+    (revisiting: the out block index depends only on the bag block, so
+    Pallas keeps it resident across the pooling-tile steps — the
+    Accumulation Configuration Register).
 
-Blocking (bag-tiled): grid = (B, ceil(L / block_l)).  Each grid step owns one
-*tile* of ``block_l`` pooling entries of one bag and runs a double-buffered
-DMA loop over the tile's rows.  Compared with the old one-row-per-step
-(B, L) grid this cuts grid-dispatch overhead by ``block_l`` and keeps the
-accumulator revisit count at ``ceil(L / block_l)`` instead of ``L``.  Tail
-tiles (L % block_l != 0) are masked: out-of-range entries fold into weight 0
-and their DMA is clamped to the last valid entry.  D is padded to the 128-lane
-boundary by ``kernels/ops.py`` when targeting real hardware (see
-EXPERIMENTS.md §Perf).  VMEM working set per step = 2 scratch rows + the
-(1, D) accumulator ≈ 3*D*4 bytes — far below the ~16 MB/core VMEM budget.
+Blocking (bag-tiled): grid = (ceil(B / BB), ceil(L / block_l)).  Each grid
+step owns ``BB`` bags (a sublane-aligned ``(BB, D)`` output block, BB a
+multiple of 8) and one *tile* of ``block_l`` pooling entries of each, and
+runs a double-buffered DMA loop over the ``BB * block_l`` rows.  Tail tiles
+(L % block_l != 0) are masked: out-of-range entries fold into factor 0 and
+their DMA is clamped to the last valid entry.  Each bag accumulates its
+entries in ascending l — the fixed order the jnp oracles reproduce, which
+is why kernel and oracle agree bit-for-bit in fp32.
+
+Row DMAs follow the chip's HBM tiling: D must be a 128-lane multiple
+(the engine stores rows ``PagingConfig.store_dim`` wide), and an int8 table is DMA'd in 8-row tiles, the
+wanted row selected in VMEM (``_RowGather``).
 
 Ownership masking for the sharded engine (``masked_sls_pallas``): a shard
-that does not own a row folds the miss into weight=0 and remaps the index to
+that does not own a row folds the miss into factor 0 and remaps the index to
 row 0 — the DMA still happens but targets a single always-resident line,
 mirroring how the paper's switch drops non-local candidates without stalling
 (section IV-C1).  Semantics match ``core/sls.masked_partial_sls`` on dense
@@ -41,212 +47,286 @@ from typing import Optional
 
 import jax
 import jax.numpy as jnp
-import numpy as np
 from jax.experimental import pallas as pl
 from jax.experimental.pallas import tpu as pltpu
 
+SUBLANES = 8
+LANES = 128     # vector lane width: the chip DMAs rows in whole lane tiles
 
-def _make_sls_kernel(L: int, block_l: int, has_mask: bool, has_weights: bool,
-                     has_scales: bool = False):
-    """Build a bag-tiled SLS kernel body for a static (L, block_l, flags)."""
+
+def _default_interpret() -> bool:
+    return jax.default_backend() != "tpu"
+
+
+def _check_lanes(D: int, interpret: bool) -> None:
+    if not interpret and D % LANES:
+        raise ValueError(f"row width {D} is not a multiple of {LANES} lanes; "
+                         "store rows lane-aligned (PagingConfig.store_dim)")
+
+
+def _round_up(n: int, m: int) -> int:
+    return -(-n // m) * m
+
+
+def _bag_blocks(B: int, block_b: int) -> tuple:
+    """(padded B, bags per grid step): the step's bag count is a multiple
+    of the 8-row sublane tile that divides the padded batch."""
+    Bp = _round_up(max(B, 1), SUBLANES)
+    BB = min(_round_up(max(block_b, 1), SUBLANES), Bp)
+    while Bp % BB:
+        BB -= SUBLANES
+    return Bp, BB
+
+
+def _pad_rows(a: jax.Array, n: int) -> jax.Array:
+    """Zero-pad the leading dim of ``a`` to ``n`` (pad bags gather row 0
+    with factor 0, so they contribute nothing and are sliced off)."""
+    if a.shape[0] == n:
+        return a
+    return jnp.pad(a, [(0, n - a.shape[0])] + [(0, 0)] * (a.ndim - 1))
+
+
+def _tri_pack(z: jax.Array, F: int, self_interaction: bool = False
+              ) -> jax.Array:
+    """Packed lower triangle of ``z`` (BB, F, F) -> (BB, P) from static
+    slices, in ``np.tril_indices`` order (row-major)."""
+    k = 1 if self_interaction else 0
+    return jnp.concatenate([z[:, a, :a + k] for a in range(1 - k, F)],
+                           axis=1)
+
+
+def _interact(feats: jax.Array, out_dtype, self_interaction: bool = False
+              ) -> jax.Array:
+    """Pairwise dots of the (BB, F, D) features, packed (BB, P) — the op
+    structure of ``ref.dot_interaction_ref`` (one batched matmul, then a
+    selection), so kernel and oracle agree bit-for-bit."""
+    z = jax.lax.dot_general(feats, feats, (((2,), (2,)), ((0,), (0,))),
+                            preferred_element_type=out_dtype)   # (BB, F, F)
+    return _tri_pack(z, feats.shape[1], self_interaction)
+
+
+class _RowGather:
+    """Double-buffered single-row gather from an HBM table into VMEM.
+
+    ``land`` is the ``(2, R, D)`` landing pad.  4-byte rows are DMA'd one
+    at a time (R = 1); narrower dtypes pack several rows per sublane, so
+    the chip only DMAs them in aligned 8-row tiles — the tile holding the
+    row lands and :meth:`read` selects it (an exact masked sum)."""
+
+    def __init__(self, table_ref, land, sem):
+        self.table, self.land, self.sem = table_ref, land, sem
+        self.V = table_ref.shape[0]
+        self.R = land.shape[1]
+
+    @staticmethod
+    def rows_per_dma(table_rows: int, dtype) -> int:
+        if jnp.dtype(dtype).itemsize >= 4:
+            return 1
+        return max(1, min(SUBLANES, table_rows))
+
+    def _start_row(self, r):
+        if self.R == 1:
+            return r
+        return jnp.minimum((r // self.R) * self.R, self.V - self.R)
+
+    def copy(self, slot, r):
+        return pltpu.make_async_copy(
+            self.table.at[pl.ds(self._start_row(r), self.R)],
+            self.land.at[slot], self.sem.at[slot])
+
+    def read(self, slot, r, dtype) -> jax.Array:
+        """The landed row ``r`` as a (1, D) ``dtype`` value."""
+        blk = self.land[slot].astype(dtype)                     # (R, D)
+        if self.R == 1:
+            return blk
+        sel = (jax.lax.broadcasted_iota(jnp.int32, blk.shape, 0)
+               == r - self._start_row(r))
+        return jnp.sum(jnp.where(sel, blk, 0), axis=0, keepdims=True)
+
+
+def _landing(table: jax.Array, D: int):
+    R = _RowGather.rows_per_dma(table.shape[0], table.dtype)
+    return pltpu.VMEM((2, R, D), table.dtype)
+
+
+def _stage_unique(uniq_ref, n_ref, scale_ref, gather: _RowGather, staging,
+                  acc_dtype) -> None:
+    """Gather-once prologue: DMA each live unique row (sentinel and padded
+    slots clamped into range; at least one slot so a nothing-owned plan
+    still reads initialized staging), dequantize it once, and store it into
+    the persistent ``(U, D)`` VMEM staging."""
+    n = jnp.maximum(n_ref[0], 1)
+
+    def row(u):
+        return jnp.minimum(uniq_ref[u], gather.V - 1)
+
+    gather.copy(0, row(0)).start()
+
+    def body(u, carry):
+        slot = u % 2
+
+        @pl.when(u + 1 < n)
+        def _prefetch_next():
+            gather.copy((u + 1) % 2, row(u + 1)).start()
+
+        gather.copy(slot, row(u)).wait()
+        r = gather.read(slot, row(u), acc_dtype)
+        if scale_ref is not None:
+            r = r * scale_ref[u].astype(acc_dtype)
+        staging[pl.ds(u, 1)] = r
+        return carry
+
+    jax.lax.fori_loop(0, n, body, 0)
+
+
+def _factors(owned: Optional[jax.Array], weights: Optional[jax.Array],
+             shape) -> jax.Array:
+    """Per-entry accumulate factor ``owned * w`` (the oracles' order)."""
+    f = (jnp.ones(shape, jnp.float32) if owned is None
+         else owned.astype(jnp.float32))
+    if weights is not None:
+        f = f * weights.astype(jnp.float32)
+    return f
+
+
+# ---------------------------------------------------------------------------
+# Masked partial SLS (split datapath)
+# ---------------------------------------------------------------------------
+
+
+def _make_sls_kernel(L: int, block_l: int, BB: int, has_scales: bool,
+                     dedup: bool):
+    """Bag-tiled masked SLS body for a static (L, block_l, BB, flags).
+
+    ``dedup``: the gather-once variant — the very first grid step stages
+    each *unique* row (``_stage_unique``), and every entry then reads its
+    row from VMEM staging through its slot; the accumulate sees the same
+    operands in the same order, so both variants agree bit-for-bit."""
+    n_entries = BB * block_l
 
     def kernel(*refs):
-        # scalar-prefetch refs first (idx[, owned][, w][, scales]), then
-        # table/out/scratch
         it = iter(refs)
-        idx_ref = next(it)
-        owned_ref = next(it) if has_mask else None
-        w_ref = next(it) if has_weights else None
-        s_ref = next(it) if has_scales else None
-        table_ref = next(it)      # (V, D) in ANY/HBM — manually DMA'd
-        out_ref = next(it)        # (1, D) accumulator block, revisited per bag
-        scratch = next(it)        # (2, D) VMEM double buffer
-        sem = next(it)            # (2,) DMA semaphores
+        if dedup:
+            uniq_ref = next(it)       # (U,) unique rows, sentinel-padded
+            n_ref = next(it)          # (1,) live staging slots
+            us_ref = next(it) if has_scales else None   # (U,) scales
+            slots_ref = next(it)      # (BB, L) SMEM staging slot per entry
+        else:
+            rows_ref = next(it)       # (BB, L) SMEM row per entry
+        f_ref = next(it)              # (BB, L) SMEM accumulate factor
+        s_ref = next(it) if has_scales and not dedup else None
+        table_ref = next(it)          # (V, D) in HBM — manually DMA'd
+        out_ref = next(it)            # (BB, D) accumulator, revisited per t
+        if dedup:
+            staging = next(it)        # (U, D) VMEM, persists across steps
+        land = next(it)               # (2, R, D) VMEM DMA double buffer
+        sem = next(it)                # (2,) DMA semaphores
 
         b = pl.program_id(0)
         t = pl.program_id(1)
         l0 = t * block_l
+        acc_dtype = out_ref.dtype
+        gather = _RowGather(table_ref, land, sem)
+
+        if dedup:
+            @pl.when((b == 0) & (t == 0))
+            def _fill_staging():
+                _stage_unique(uniq_ref, n_ref, us_ref, gather, staging,
+                              acc_dtype)
 
         @pl.when(t == 0)
         def _init():
             out_ref[...] = jnp.zeros_like(out_ref)
 
-        def row_dma(slot, i):
-            # clamp tail-tile reads into range; masked-out rows remap to the
-            # always-resident line 0 (their contribution is zeroed below)
-            l = jnp.minimum(l0 + i, L - 1)
-            r = idx_ref[b, l]
-            if has_mask:
-                r = jnp.where(owned_ref[b, l] != 0, r, 0)
-            return pltpu.make_async_copy(table_ref.at[r], scratch.at[slot],
-                                         sem.at[slot])
+        def entry(k):
+            i = k // block_l
+            return i, jnp.minimum(l0 + k % block_l, L - 1)
 
-        row_dma(0, 0).start()
+        if not dedup:
+            def dma(slot, k):
+                i, l = entry(k)
+                return gather.copy(slot, rows_ref[i, l])
 
-        def body(i, carry):
-            slot = i % 2
+            dma(0, 0).start()
 
-            @pl.when(i + 1 < block_l)
-            def _prefetch_next():
-                row_dma((i + 1) % 2, i + 1).start()
+        def body(k, carry):
+            i, lc = entry(k)
+            valid = (l0 + k % block_l < L).astype(acc_dtype)
+            f = valid * f_ref[i, lc].astype(acc_dtype)
+            if dedup:
+                row = staging[pl.ds(slots_ref[i, lc], 1)]     # VMEM read
+            else:
+                slot = k % 2
 
-            row_dma(slot, i).wait()
-            l = l0 + i
-            lc = jnp.minimum(l, L - 1)
-            f = (l < L).astype(out_ref.dtype)
-            if has_mask:
-                f = f * (owned_ref[b, lc] != 0).astype(out_ref.dtype)
-            if has_weights:
-                f = f * w_ref[b, lc].astype(out_ref.dtype)
-            row = scratch[slot][None, :].astype(out_ref.dtype)
-            if has_scales:
-                # fused dequant: the int8 row is scaled to fp32 *after* its
-                # (1-byte-per-element) DMA landed — an fp32 copy of the cold
-                # shard never exists, only this (1, D) working row
-                row = row * s_ref[b, lc].astype(out_ref.dtype)
-            out_ref[...] += f * row
+                @pl.when(k + 1 < n_entries)
+                def _prefetch_next():
+                    dma((k + 1) % 2, k + 1).start()
+
+                dma(slot, k).wait()
+                row = gather.read(slot, rows_ref[i, lc], acc_dtype)
+                if has_scales:
+                    # fused dequant: the int8 row is scaled to fp32 after
+                    # its DMA landed — an fp32 copy of the cold shard
+                    # never exists, only this (1, D) working row
+                    row = row * s_ref[i, lc].astype(acc_dtype)
+            out_ref[pl.ds(i, 1)] = out_ref[pl.ds(i, 1)] + f * row
             return carry
 
-        jax.lax.fori_loop(0, block_l, body, 0)
+        jax.lax.fori_loop(0, n_entries, body, 0)
 
     return kernel
 
 
 def _sls_call(table: jax.Array, indices: jax.Array,
               owned: Optional[jax.Array], weights: Optional[jax.Array],
-              scales: Optional[jax.Array],
-              out_dtype, interpret: bool, block_l: int) -> jax.Array:
+              scales: Optional[jax.Array], out_dtype, interpret: bool,
+              block_l: int, dedup_plan=None) -> jax.Array:
     B, L = indices.shape
     V, D = table.shape
     if B == 0 or L == 0:
         return jnp.zeros((B, D), out_dtype)
+    _check_lanes(D, interpret)
     block_l = max(1, min(block_l, L))
-    grid = (B, pl.cdiv(L, block_l))
-
-    prefetch = [indices.astype(jnp.int32)]
-    if owned is not None:
-        prefetch.append(owned.astype(jnp.int32))
-    if weights is not None:
-        prefetch.append(weights)
-    if scales is not None:
-        prefetch.append(scales.astype(jnp.float32))
-
-    def out_map(b, t, *prefetch_refs):
-        return (b, 0)
+    Bp, BB = _bag_blocks(B, SUBLANES)
+    f = _pad_rows(_factors(owned, weights, (B, L)), Bp)
+    stream = pl.BlockSpec((BB, L), lambda b, t, *_: (b, 0),
+                          memory_space=pltpu.SMEM)
+    scratch = [_landing(table, D), pltpu.SemaphoreType.DMA((2,))]
+    if dedup_plan is not None:
+        uniq, slots, n_slots, uscales = dedup_plan
+        prefetch = [uniq.astype(jnp.int32),
+                    n_slots.astype(jnp.int32).reshape(1)]
+        if uscales is not None:
+            prefetch.append(uscales.astype(jnp.float32))
+        blocked = [_pad_rows(slots.astype(jnp.int32), Bp), f]
+        scratch = [pltpu.VMEM((uniq.shape[0], D), out_dtype)] + scratch
+        has_scales = uscales is not None
+    else:
+        prefetch = []
+        rows = indices.astype(jnp.int32)
+        if owned is not None:
+            rows = jnp.where(owned, rows, 0)
+        blocked = [_pad_rows(rows, Bp), f]
+        if scales is not None:
+            blocked.append(_pad_rows(scales.astype(jnp.float32), Bp))
+        has_scales = scales is not None
 
     grid_spec = pltpu.PrefetchScalarGridSpec(
         num_scalar_prefetch=len(prefetch),
-        grid=grid,
-        in_specs=[pl.BlockSpec(memory_space=pltpu.ANY)],   # table stays in HBM
-        out_specs=pl.BlockSpec((1, D), out_map),
-        scratch_shapes=[pltpu.VMEM((2, D), table.dtype),
-                        pltpu.SemaphoreType.DMA((2,))],
+        grid=(Bp // BB, pl.cdiv(L, block_l)),
+        in_specs=[stream] * len(blocked)
+        + [pl.BlockSpec(memory_space=pl.ANY)],           # table stays in HBM
+        out_specs=pl.BlockSpec((BB, D), lambda b, t, *_: (b, 0)),
+        scratch_shapes=scratch,
     )
-    kernel = _make_sls_kernel(L, block_l, has_mask=owned is not None,
-                              has_weights=weights is not None,
-                              has_scales=scales is not None)
-    return pl.pallas_call(
+    kernel = _make_sls_kernel(L, block_l, BB, has_scales=has_scales,
+                              dedup=dedup_plan is not None)
+    out = pl.pallas_call(
         kernel, grid_spec=grid_spec,
-        out_shape=jax.ShapeDtypeStruct((B, D), out_dtype),
+        out_shape=jax.ShapeDtypeStruct((Bp, D), out_dtype),
         interpret=interpret,
-    )(*prefetch, table)
-
-
-def _make_sls_dedup_kernel(L: int, block_l: int, has_weights: bool,
-                           has_scales: bool):
-    """Two-phase gather-once dedup'd SLS kernel body.
-
-    Phase 1 (first grid step only): double-buffered DMA of each *unique*
-    row from the HBM table into a VMEM landing pad, fused per-row dequant
-    (``float(row) * scale``), store into the persistent (U, D) VMEM staging
-    buffer.  The DMA loop is bounded by the *traced* live-slot count, so
-    the bytes moved scale with the realized unique count, not the padded
-    capacity.
-
-    Phase 2 (every grid step): the bag-tiled fixed-l-order accumulate of
-    ``_make_sls_kernel``, but each entry's row is a VMEM read from staging
-    through the slot indirection — no per-entry DMA.  The accumulate sees
-    the same operands in the same order as the non-dedup kernel (the
-    dequant multiply moved from per-entry to per-unique-row with identical
-    inputs), so the two are bit-for-bit equal in fp32.
-    """
-
-    def kernel(*refs):
-        # scalar-prefetch refs first (slots, owned[, w], uniq, n[, scales]),
-        # then table/out/scratch
-        it = iter(refs)
-        slots_ref = next(it)      # (B, L) staging slot per pooling entry
-        owned_ref = next(it)      # (B, L) ownership mask
-        w_ref = next(it) if has_weights else None
-        uniq_ref = next(it)       # (U,) unique row ids, sentinel-padded
-        n_ref = next(it)          # (1,) live staging slots
-        s_ref = next(it) if has_scales else None   # (U,) dequant scales
-        table_ref = next(it)      # (V, D) in ANY/HBM — manually DMA'd
-        out_ref = next(it)        # (1, D) accumulator block, revisited per bag
-        staging = next(it)        # (U, D) VMEM staging, persists across steps
-        landing = next(it)        # (2, D) VMEM DMA double buffer
-        sem = next(it)            # (2,) DMA semaphores
-
-        b = pl.program_id(0)
-        t = pl.program_id(1)
-        V = table_ref.shape[0]
-
-        @pl.when((b == 0) & (t == 0))
-        def _fill_staging():
-            # gather-once: each unique row crosses the memory interface
-            # exactly once; duplicates are served from VMEM in phase 2.
-            # At least one slot is always fetched so the sentinel-only
-            # (nothing owned) case still reads initialized staging.
-            n = jnp.maximum(n_ref[0], 1)
-
-            def row_dma(u, slot):
-                # clamp the sentinel (and padded slots) into range — the
-                # fetched line is masked to zero contribution in phase 2
-                r = jnp.minimum(uniq_ref[u], V - 1)
-                return pltpu.make_async_copy(table_ref.at[r],
-                                             landing.at[slot], sem.at[slot])
-
-            row_dma(0, 0).start()
-
-            def body(u, carry):
-                slot = u % 2
-
-                @pl.when(u + 1 < n)
-                def _prefetch_next():
-                    row_dma(u + 1, (u + 1) % 2).start()
-
-                row_dma(u, slot).wait()
-                row = landing[slot].astype(out_ref.dtype)
-                if has_scales:
-                    # fused dequant: scaled once per *unique* row, after its
-                    # (1-byte-per-element) DMA landed — same operands as the
-                    # non-dedup kernel's per-entry multiply
-                    row = row * s_ref[u].astype(out_ref.dtype)
-                staging[pl.ds(u, 1)] = row[None, :]
-                return carry
-
-            jax.lax.fori_loop(0, n, body, 0)
-
-        @pl.when(t == 0)
-        def _init():
-            out_ref[...] = jnp.zeros_like(out_ref)
-
-        l0 = t * block_l
-
-        def body(i, carry):
-            l = l0 + i
-            lc = jnp.minimum(l, L - 1)
-            f = (l < L).astype(out_ref.dtype)
-            f = f * (owned_ref[b, lc] != 0).astype(out_ref.dtype)
-            if has_weights:
-                f = f * w_ref[b, lc].astype(out_ref.dtype)
-            row = staging[slots_ref[b, lc]][None, :]   # VMEM read, no DMA
-            out_ref[...] += f * row
-            return carry
-
-        jax.lax.fori_loop(0, block_l, body, 0)
-
-    return kernel
+    )(*prefetch, *blocked, table)
+    return out[:B]
 
 
 @functools.partial(jax.jit,
@@ -256,7 +336,8 @@ def masked_sls_dedup_pallas(table: jax.Array, unique_rows: jax.Array,
                             n_slots: jax.Array,
                             weights: Optional[jax.Array] = None,
                             unique_scales: Optional[jax.Array] = None,
-                            out_dtype=jnp.float32, interpret: bool = True,
+                            out_dtype=jnp.float32,
+                            interpret: Optional[bool] = None,
                             block_l: int = 8) -> jax.Array:
     """Gather-once dedup'd masked partial SLS (oracle:
     ``kernels/ref.py:masked_sls_dedup_ref``).
@@ -264,71 +345,81 @@ def masked_sls_dedup_pallas(table: jax.Array, unique_rows: jax.Array,
     ``unique_rows (U,)`` / ``slots (B, L)`` / ``n_slots (1,)`` come from
     ``core/sls.dedup_plan`` (U = B*L capacity, sentinel-padded).  Grid and
     accumulate structure match ``masked_sls_pallas``; the table DMA happens
-    once per unique row in a phase-1 prologue instead of once per pooling
-    entry.  Both grid dims must execute sequentially (staging is written at
-    the first step and read by all later ones) — they are "arbitrary"
-    semantics, which is the Pallas TPU default and the interpret-mode
-    execution order.
+    once per unique row in a prologue on the first grid step instead of
+    once per pooling entry.  Both grid dims must execute sequentially
+    (staging is written at the first step and read by all later ones) —
+    they are "arbitrary" semantics, the Pallas TPU default.
     """
-    B, L = slots.shape
-    V, D = table.shape
-    if B == 0 or L == 0:
-        return jnp.zeros((B, D), out_dtype)
-    block_l = max(1, min(block_l, L))
-    grid = (B, pl.cdiv(L, block_l))
-    U = unique_rows.shape[0]
+    if interpret is None:
+        interpret = _default_interpret()
+    return _sls_call(table, slots, owned, weights, None, out_dtype,
+                     interpret, block_l,
+                     dedup_plan=(unique_rows, slots, n_slots, unique_scales))
 
-    prefetch = [slots.astype(jnp.int32), owned.astype(jnp.int32)]
-    if weights is not None:
-        prefetch.append(weights)
-    prefetch.append(unique_rows.astype(jnp.int32))
-    prefetch.append(n_slots.astype(jnp.int32).reshape(1))
-    if unique_scales is not None:
-        prefetch.append(unique_scales.astype(jnp.float32))
 
-    def out_map(b, t, *prefetch_refs):
-        return (b, 0)
+@functools.partial(jax.jit,
+                   static_argnames=("out_dtype", "interpret", "block_l"))
+def sls_pallas(table: jax.Array, indices: jax.Array,
+               weights: Optional[jax.Array] = None,
+               out_dtype=jnp.float32, interpret: Optional[bool] = None,
+               block_l: int = 8) -> jax.Array:
+    """SLS via pl.pallas_call. indices: (B, L) int32 -> (B, D) pooled."""
+    if interpret is None:
+        interpret = _default_interpret()
+    return _sls_call(table, indices, None, weights, None, out_dtype,
+                     interpret, block_l)
 
-    grid_spec = pltpu.PrefetchScalarGridSpec(
-        num_scalar_prefetch=len(prefetch),
-        grid=grid,
-        in_specs=[pl.BlockSpec(memory_space=pltpu.ANY)],   # table stays in HBM
-        out_specs=pl.BlockSpec((1, D), out_map),
-        scratch_shapes=[pltpu.VMEM((U, D), out_dtype),     # staging
-                        pltpu.VMEM((2, D), table.dtype),   # DMA landing pad
-                        pltpu.SemaphoreType.DMA((2,))],
-    )
-    kernel = _make_sls_dedup_kernel(L, block_l,
-                                    has_weights=weights is not None,
-                                    has_scales=unique_scales is not None)
-    return pl.pallas_call(
-        kernel, grid_spec=grid_spec,
-        out_shape=jax.ShapeDtypeStruct((B, D), out_dtype),
-        interpret=interpret,
-    )(*prefetch, table)
+
+@functools.partial(jax.jit,
+                   static_argnames=("out_dtype", "interpret", "block_l"))
+def masked_sls_pallas(table: jax.Array, indices: jax.Array, owned: jax.Array,
+                      weights: Optional[jax.Array] = None,
+                      scales: Optional[jax.Array] = None,
+                      out_dtype=jnp.float32, interpret: Optional[bool] = None,
+                      block_l: int = 8) -> jax.Array:
+    """Masked partial SLS: out[b] = sum_l owned[b,l]*w[b,l]*table[idx[b,l]].
+
+    The per-shard operator of the PIFS engine: ``owned`` marks the pooling
+    entries whose rows live on this shard; everything else contributes zero
+    (and its gather is remapped to row 0, which must exist).
+
+    Optional ``scales`` (B, L): per-entry dequant scales for a quantized
+    (int8) ``table``.  Each DMA'd row is dequantized in VMEM
+    (``float(row) * scale``) right before the weighted accumulate — the
+    tiered-precision store's fused-dequant datapath (oracle:
+    ``kernels/ref.py:masked_sls_quant_ref``).
+    """
+    if interpret is None:
+        interpret = _default_interpret()
+    return _sls_call(table, indices, owned, weights, scales, out_dtype,
+                     interpret, block_l)
+
+
+# ---------------------------------------------------------------------------
+# Fused front end: SLS -> dot-interaction
+# ---------------------------------------------------------------------------
 
 
 def _make_fused_front_end_kernel(L: int, block_l: int, G: int, BB: int,
-                                 has_weights: bool, has_scales: bool,
-                                 dedup: bool, emit: str = "interact"):
+                                 has_scales: bool, dedup: bool,
+                                 emit: str = "interact"):
     """Fused DLRM front-end kernel body: SLS -> dot-interaction, one kernel.
 
-    Three phases over grid ``(B // BB, G, ceil(L / block_l))``:
+    Three phases over grid ``(Bp // BB, G, ceil(L / block_l))``:
 
       * phase 1 (``dedup`` only, very first grid step): gather-once DMA of
         each unique cold/hot row into persistent ``(U, D)`` VMEM row
-        staging, fused per-row dequant — identical structure to
-        ``_make_sls_dedup_kernel``'s prologue, once per *tier*.
-      * phase 2 (every step): the bag-tiled fixed-l-order masked accumulate,
-        writing pooled rows into persistent VMEM *feature staging* laid out
-        as ``(BB, F, D)`` batch-tiles (flattened ``(BB*F, D)`` scratch; one
-        accumulator pair per tier so the final ``cold + hot`` add matches
-        the split datapath's ``psum(cold_part) + hot_out`` bit-for-bit).
-        Feature row 0 of each sample is the bottom-MLP output (``x``),
-        loaded once per batch-tile.
-      * phase 3 (last ``(g, t)`` step of each batch-tile): the
-        dot-interaction matmul + static triangle pack of
-        ``_interaction_kernel`` on the resident ``(BB, F, D)`` features.
+        staging, fused per-row dequant — ``_stage_unique``, once per tier.
+      * phase 2 (every step): the bag-tiled fixed-l-order masked accumulate
+        of table ``g``, writing pooled rows into persistent VMEM *feature
+        staging* ``(BB, F, D)`` (one accumulator per tier so the final
+        ``cold + hot`` add matches the split datapath's
+        ``psum(cold_part) + hot_out`` bit-for-bit).  Feature row 0 of each
+        sample is the bottom-MLP output (``x``), stored once per batch
+        tile.
+      * phase 3 (last ``(g, t)`` step of each batch tile): the
+        dot-interaction matmul + static triangle pack (``_interact``) on
+        the resident features.
 
     ``emit`` selects where the pipeline stops:
 
@@ -347,31 +438,24 @@ def _make_fused_front_end_kernel(L: int, block_l: int, G: int, BB: int,
     ``(BB, P)`` packed triangle (or the two ``(BB, F, D)`` partial tiles)
     out.
     """
-    F = G + 1
     interact = emit == "interact"
+    n_entries = BB * block_l
 
     def kernel(*refs):
         it = iter(refs)
-        if dedup:
-            cslots_ref = next(it)   # (B, G, L) cold staging slot per entry
-            hslots_ref = next(it)   # (B, G, L) hot staging slot per entry
-        else:
-            rows_ref = next(it)     # (B, G, L) local row per entry
-        owned_ref = next(it)        # (B, G, L) cold-tier ownership mask
-        hot_ref = next(it)          # (B, G, L) hot-tier membership mask
-        w_ref = next(it) if has_weights else None
         if dedup:
             cuniq_ref = next(it)    # (U,) unique cold rows, sentinel-padded
             cn_ref = next(it)       # (1,) live cold staging slots
             cs_ref = next(it) if has_scales else None   # (U,) dequant scales
             huniq_ref = next(it)    # (U,) unique hot rows, sentinel-padded
             hn_ref = next(it)       # (1,) live hot staging slots
-        elif has_scales:
-            s_ref = next(it)        # (B, G, L) per-entry dequant scales
-        if interact:
-            tri_ref = next(it)      # (P,) static triangle-pack permutation
-        cold_ref = next(it)         # (Vc, D) ANY/HBM — manually DMA'd
-        hot_table_ref = next(it)    # (Vh, D) ANY/HBM — manually DMA'd
+        crow_ref = next(it)         # (BB, L) SMEM cold row / staging slot
+        hrow_ref = next(it)         # (BB, L) SMEM hot row / staging slot
+        fc_ref = next(it)           # (BB, L) SMEM cold factor owned * w
+        fh_ref = next(it)           # (BB, L) SMEM hot factor is_hot * w
+        s_ref = next(it) if has_scales and not dedup else None
+        cold_ref = next(it)         # (Vc, D) in HBM — manually DMA'd
+        hot_table_ref = next(it)    # (Vh, D) in HBM — manually DMA'd
         x_ref = next(it)            # (BB, D) bottom-MLP block (auto-piped)
         if interact:
             out_ref = next(it)      # (BB, P) packed-triangle block
@@ -383,10 +467,10 @@ def _make_fused_front_end_kernel(L: int, block_l: int, G: int, BB: int,
         if dedup:
             crows = next(it)        # (U, D) VMEM cold row staging (dequant'd)
             hrows = next(it)        # (U, D) VMEM hot row staging
-        stage_c = next(it)          # (BB*F, D) VMEM cold feature staging
-        stage_h = next(it)          # (BB*F, D) VMEM hot feature staging
-        cland = next(it)            # (2, D) cold DMA double buffer
-        hland = next(it)            # (2, D) hot DMA double buffer
+        stage_c = next(it)          # (BB, F, D) VMEM cold feature staging
+        stage_h = next(it)          # (BB, F, D) VMEM hot feature staging
+        cland = next(it)            # (2, R, D) cold DMA double buffer
+        hland = next(it)            # (2, 1, D) hot DMA double buffer
         csem = next(it)             # (2,) cold DMA semaphores
         hsem = next(it)             # (2,) hot DMA semaphores
 
@@ -395,166 +479,180 @@ def _make_fused_front_end_kernel(L: int, block_l: int, G: int, BB: int,
         t = pl.program_id(2)
         n_tl = pl.num_programs(2)
         l0 = t * block_l
+        cgather = _RowGather(cold_ref, cland, csem)
+        hgather = _RowGather(hot_table_ref, hland, hsem)
 
         if dedup:
             @pl.when((bt == 0) & (g == 0) & (t == 0))
             def _fill_row_staging():
                 # gather-once per tier: each unique row crosses the memory
                 # interface exactly once; phase 2 reads VMEM only.
-                for uniq_ref, n_ref, land, sem, staging, table, sref in (
-                        (cuniq_ref, cn_ref, cland, csem, crows, cold_ref,
-                         cs_ref),
-                        (huniq_ref, hn_ref, hland, hsem, hrows,
-                         hot_table_ref, None)):
-                    V = table.shape[0]
-                    n = jnp.maximum(n_ref[0], 1)
-
-                    def row_dma(u, slot, *, _t=table, _l=land, _s=sem,
-                                _u=uniq_ref, _V=V):
-                        r = jnp.minimum(_u[u], _V - 1)
-                        return pltpu.make_async_copy(_t.at[r], _l.at[slot],
-                                                     _s.at[slot])
-
-                    row_dma(0, 0).start()
-
-                    def body(u, carry, *, _land=land, _staging=staging,
-                             _sref=sref, _n=n, _dma=row_dma):
-                        slot = u % 2
-
-                        @pl.when(u + 1 < _n)
-                        def _prefetch_next():
-                            _dma(u + 1, (u + 1) % 2).start()
-
-                        _dma(u, slot).wait()
-                        row = _land[slot].astype(acc_dtype)
-                        if _sref is not None:
-                            row = row * _sref[u].astype(acc_dtype)
-                        _staging[pl.ds(u, 1)] = row[None, :]
-                        return carry
-
-                    jax.lax.fori_loop(0, n, body, 0)
+                _stage_unique(cuniq_ref, cn_ref, cs_ref, cgather, crows,
+                              acc_dtype)
+                _stage_unique(huniq_ref, hn_ref, None, hgather, hrows,
+                              acc_dtype)
 
         @pl.when((g == 0) & (t == 0))
         def _init_features():
-            # per batch-tile: zero both accumulators, land the bottom-MLP
-            # output in feature row 0 of the cold staging (the hot staging's
-            # row 0 stays zero, so the phase-3 add reproduces the split
-            # path's `concat([x, pooled])` exactly).  In tiles mode x rides
-            # the *hot* staging instead: hot is replicated across tp shards
-            # while the cold tile is psum'd, so this is the placement that
-            # counts x once.
-            xv = x_ref[...].astype(acc_dtype)                   # (BB, D)
-            D = xv.shape[-1]
-            init = jnp.zeros((BB, F, D), acc_dtype)
-            with_x = init.at[:, 0, :].set(xv).reshape(BB * F, D)
-            if interact:
-                stage_c[...] = with_x
-                stage_h[...] = jnp.zeros_like(stage_h)
-            else:
-                stage_c[...] = jnp.zeros_like(stage_c)
-                stage_h[...] = with_x
+            # per batch tile: zero both accumulators, land the bottom-MLP
+            # output in feature row 0 of the cold staging (the hot
+            # staging's row 0 stays zero, so the phase-3 add reproduces the
+            # split path's `concat([x, pooled])` exactly).  In tiles mode x
+            # rides the *hot* staging instead: hot is replicated across tp
+            # shards while the cold tile is psum'd, so this is the
+            # placement that counts x once.
+            stage_c[...] = jnp.zeros_like(stage_c)
+            stage_h[...] = jnp.zeros_like(stage_h)
+            with_x = stage_c if interact else stage_h
+            with_x[:, 0, :] = x_ref[...].astype(acc_dtype)
+
+        def entry(k):
+            i = k // block_l
+            return i, jnp.minimum(l0 + k % block_l, L - 1)
 
         if not dedup:
-            def entry_dma(slot, k):
-                # one DMA per tier per entry; out-of-tier entries remap to
-                # the always-resident line 0 of that tier's table (their
-                # contribution is zeroed below) — same trick as
-                # ``_make_sls_kernel``'s ownership masking
-                i = k // block_l
-                l = jnp.minimum(l0 + k % block_l, L - 1)
-                b = bt * BB + i
-                r = rows_ref[b, g, l]
-                rc = jnp.where(owned_ref[b, g, l] != 0, r, 0)
-                rh = jnp.where(hot_ref[b, g, l] != 0, r, 0)
-                return (pltpu.make_async_copy(cold_ref.at[rc], cland.at[slot],
-                                              csem.at[slot]),
-                        pltpu.make_async_copy(hot_table_ref.at[rh],
-                                              hland.at[slot], hsem.at[slot]))
-
             def start(slot, k):
-                c, h = entry_dma(slot, k)
-                c.start()
-                h.start()
+                # one DMA per tier per entry; out-of-tier entries were
+                # remapped to the always-resident line 0 of that tier's
+                # table by the wrapper (their factor is zero)
+                i, l = entry(k)
+                cgather.copy(slot, crow_ref[i, l]).start()
+                hgather.copy(slot, hrow_ref[i, l]).start()
 
             start(0, 0)
 
-        n_entries = BB * block_l
-
         def body(k, carry):
-            i = k // block_l
-            l = l0 + k % block_l
-            lc = jnp.minimum(l, L - 1)
-            b = bt * BB + i
-            if not dedup:
+            i, lc = entry(k)
+            valid = (l0 + k % block_l < L).astype(acc_dtype)
+            fc = valid * fc_ref[i, lc].astype(acc_dtype)
+            fh = valid * fh_ref[i, lc].astype(acc_dtype)
+            if dedup:
+                row_c = crows[pl.ds(crow_ref[i, lc], 1)]
+                row_h = hrows[pl.ds(hrow_ref[i, lc], 1)]
+            else:
                 slot = k % 2
 
                 @pl.when(k + 1 < n_entries)
                 def _prefetch_next():
                     start((k + 1) % 2, k + 1)
 
-                c, h = entry_dma(slot, k)
-                c.wait()
-                h.wait()
-            f = (l < L).astype(acc_dtype)
-            if has_weights:
-                f = f * w_ref[b, g, lc].astype(acc_dtype)
-            fc = f * (owned_ref[b, g, lc] != 0).astype(acc_dtype)
-            fh = f * (hot_ref[b, g, lc] != 0).astype(acc_dtype)
-            if dedup:
-                row_c = crows[cslots_ref[b, g, lc]][None, :]
-                row_h = hrows[hslots_ref[b, g, lc]][None, :]
-            else:
-                row_c = cland[slot][None, :].astype(acc_dtype)
+                cgather.copy(slot, crow_ref[i, lc]).wait()
+                hgather.copy(slot, hrow_ref[i, lc]).wait()
+                row_c = cgather.read(slot, crow_ref[i, lc], acc_dtype)
                 if has_scales:
-                    row_c = row_c * s_ref[b, g, lc].astype(acc_dtype)
-                row_h = hland[slot][None, :].astype(acc_dtype)
-            sk = i * F + g + 1
-            stage_c[pl.ds(sk, 1)] = stage_c[pl.ds(sk, 1)] + fc * row_c
-            stage_h[pl.ds(sk, 1)] = stage_h[pl.ds(sk, 1)] + fh * row_h
+                    row_c = row_c * s_ref[i, lc].astype(acc_dtype)
+                row_h = hgather.read(slot, hrow_ref[i, lc], acc_dtype)
+            feat = pl.ds(g + 1, 1)
+            stage_c[i, feat] = stage_c[i, feat] + fc * row_c
+            stage_h[i, feat] = stage_h[i, feat] + fh * row_h
             return carry
 
         jax.lax.fori_loop(0, n_entries, body, 0)
 
-        if interact:
-            @pl.when((g == G - 1) & (t == n_tl - 1))
-            def _interact():
-                # phase 3: dot-interaction on the resident features —
-                # identical op structure to kernels/interaction.py's
-                # _interaction_kernel
-                D = stage_c.shape[-1]
-                feats = (stage_c[...] + stage_h[...]).reshape(BB, F, D)
-                z = jax.lax.dot_general(
-                    feats, feats, (((2,), (2,)), ((0,), (0,))),
-                    preferred_element_type=out_ref.dtype)       # (BB, F, F)
-                out_ref[...] = jnp.take(z.reshape(BB, F * F), tri_ref[...],
-                                        axis=1)
-        else:
-            @pl.when((g == G - 1) & (t == n_tl - 1))
-            def _emit_tiles():
+        @pl.when((g == G - 1) & (t == n_tl - 1))
+        def _finish():
+            if interact:
+                # phase 3: dot-interaction on the resident features
+                out_ref[...] = _interact(stage_c[...] + stage_h[...],
+                                         out_ref.dtype)
+            else:
                 # phase-2/3 seam: hand the per-tier partial tiles to the
                 # cross-shard psum; the cold/hot add happens after the
                 # reduction in the resume kernel, preserving the split
                 # path's `psum(cold_part) + hot_out` operand order.
-                D = stage_c.shape[-1]
-                out_c_ref[...] = stage_c[...].reshape(BB, F, D)
-                out_h_ref[...] = stage_h[...].reshape(BB, F, D)
+                out_c_ref[...] = stage_c[...]
+                out_h_ref[...] = stage_h[...]
 
     return kernel
 
 
-def _fe_blocks(B: int, L: int, block_l: int, block_b: int, G: int):
-    """Resolve (BB, block_l, tri, P) for a fused front-end call: the batch
-    tile must divide B (largest power-of-two shrink of ``block_b`` that
-    does), the pooling tile is clamped to L, and the triangle pack is the
-    static lower-triangle permutation of F = G + 1 features."""
-    BB = max(1, min(block_b, B))
-    while B % BB:
-        BB //= 2
-    block_l = max(1, min(block_l, L))
+def _fused_call(cold: jax.Array, hot: jax.Array, x: jax.Array,
+                rows: Optional[jax.Array], owned: jax.Array,
+                is_hot: jax.Array, weights: Optional[jax.Array],
+                scales: Optional[jax.Array], dedup_plans, out_dtype,
+                interpret: bool, block_l: int, block_b: int, emit: str):
+    """Shared pallas_call builder of the four fused front-end entry points.
+
+    ``dedup_plans`` is ``((unique, slots, n, scales), (unique, slots, n))``
+    for the cold and hot tier, or None.  The per-entry streams are laid out table-major ``(G * Bp, L)`` so one
+    grid step's ``(BB, L)`` SMEM block is contiguous."""
+    B, G, L = owned.shape
+    D = cold.shape[-1]
+    _check_lanes(D, interpret)
     F = G + 1
-    i, j = np.tril_indices(F, k=-1)
-    tri = jnp.asarray(i * F + j, jnp.int32)
-    return BB, block_l, tri, int(tri.shape[0])
+    P = F * (F - 1) // 2
+    Bp, BB = _bag_blocks(B, block_b)
+    block_l = max(1, min(block_l, L))
+    nbt = Bp // BB
+
+    def stream(a):                       # (B, G, L) -> (G * Bp, L)
+        return _pad_rows(a, Bp).transpose(1, 0, 2).reshape(G * Bp, L)
+
+    fc = _factors(owned, weights, owned.shape)
+    fh = _factors(is_hot, weights, owned.shape)
+    scratch = [pltpu.VMEM((BB, F, D), out_dtype),          # cold features
+               pltpu.VMEM((BB, F, D), out_dtype),          # hot features
+               _landing(cold, D), _landing(hot, D),
+               pltpu.SemaphoreType.DMA((2,)), pltpu.SemaphoreType.DMA((2,))]
+    if dedup_plans is not None:
+        (c_uniq, c_slots, c_n, c_scales), (h_uniq, h_slots, h_n) = dedup_plans
+        prefetch = [c_uniq.astype(jnp.int32),
+                    c_n.astype(jnp.int32).reshape(1)]
+        if c_scales is not None:
+            prefetch.append(c_scales.astype(jnp.float32))
+        prefetch += [h_uniq.astype(jnp.int32),
+                     h_n.astype(jnp.int32).reshape(1)]
+        blocked = [stream(c_slots.astype(jnp.int32)),
+                   stream(h_slots.astype(jnp.int32)),
+                   stream(fc), stream(fh)]
+        U = c_uniq.shape[0]
+        scratch = [pltpu.VMEM((U, D), out_dtype),           # cold row staging
+                   pltpu.VMEM((U, D), out_dtype)] + scratch  # hot row staging
+        has_scales = c_scales is not None
+    else:
+        prefetch = []
+        r = rows.astype(jnp.int32)
+        blocked = [stream(jnp.where(owned, r, 0)),
+                   stream(jnp.where(is_hot, r, 0)), stream(fc), stream(fh)]
+        if scales is not None:
+            blocked.append(stream(scales.astype(jnp.float32)))
+        has_scales = scales is not None
+
+    smem = pl.BlockSpec((BB, L), lambda bt, g, t, *_: (g * nbt + bt, 0),
+                        memory_space=pltpu.SMEM)
+    if emit == "interact":
+        out_specs = pl.BlockSpec((BB, P), lambda bt, g, t, *_: (bt, 0))
+        out_shape = jax.ShapeDtypeStruct((Bp, P), out_dtype)
+    else:
+        tile = pl.BlockSpec((BB, F, D), lambda bt, g, t, *_: (bt, 0, 0))
+        out_specs = [tile, tile]
+        out_shape = [jax.ShapeDtypeStruct((Bp, F, D), out_dtype)] * 2
+    grid_spec = pltpu.PrefetchScalarGridSpec(
+        num_scalar_prefetch=len(prefetch),
+        grid=(nbt, G, pl.cdiv(L, block_l)),
+        in_specs=[smem] * len(blocked)
+        + [pl.BlockSpec(memory_space=pl.ANY),              # cold stays in HBM
+           pl.BlockSpec(memory_space=pl.ANY),              # hot stays in HBM
+           pl.BlockSpec((BB, D), lambda bt, g, t, *_: (bt, 0))],
+        out_specs=out_specs,
+        scratch_shapes=scratch,
+    )
+    kernel = _make_fused_front_end_kernel(
+        L, block_l, G, BB, has_scales=has_scales,
+        dedup=dedup_plans is not None, emit=emit)
+    out = pl.pallas_call(
+        kernel, grid_spec=grid_spec, out_shape=out_shape,
+        interpret=interpret,
+    )(*prefetch, *blocked, cold, hot, _pad_rows(x, Bp))
+    if emit == "interact":
+        return out[:B]
+    return out[0][:B], out[1][:B]
+
+
+def _empty_tiles(B: int, F: int, D: int, x: jax.Array, out_dtype):
+    zc = jnp.zeros((B, F, D), out_dtype)
+    return zc, jnp.concatenate([x[:, None, :].astype(out_dtype), zc[:, 1:]],
+                               axis=1)
 
 
 @functools.partial(jax.jit, static_argnames=("out_dtype", "interpret",
@@ -578,44 +676,13 @@ def fused_front_end_pallas(cold: jax.Array, hot: jax.Array, x: jax.Array,
     (masked SLS per tier -> add -> concat -> dot-interaction) in fp32.
     """
     if interpret is None:
-        interpret = jax.default_backend() != "tpu"
+        interpret = _default_interpret()
     B, G, L = rows.shape
-    D = cold.shape[-1]
-    BB, block_l, tri, P = _fe_blocks(B, L, block_l, block_b, G)
     if B == 0 or L == 0 or G == 0:
-        return jnp.zeros((B, P), out_dtype)
-
-    prefetch = [rows.astype(jnp.int32), owned.astype(jnp.int32),
-                is_hot.astype(jnp.int32)]
-    if weights is not None:
-        prefetch.append(weights)
-    if scales is not None:
-        prefetch.append(scales.astype(jnp.float32))
-    prefetch.append(tri)
-
-    F = G + 1
-    grid_spec = pltpu.PrefetchScalarGridSpec(
-        num_scalar_prefetch=len(prefetch),
-        grid=(B // BB, G, pl.cdiv(L, block_l)),
-        in_specs=[pl.BlockSpec(memory_space=pltpu.ANY),    # cold stays in HBM
-                  pl.BlockSpec(memory_space=pltpu.ANY),    # hot stays in HBM
-                  pl.BlockSpec((BB, D), lambda bt, g, t, *p: (bt, 0))],
-        out_specs=pl.BlockSpec((BB, P), lambda bt, g, t, *p: (bt, 0)),
-        scratch_shapes=[pltpu.VMEM((BB * F, D), out_dtype),  # cold features
-                        pltpu.VMEM((BB * F, D), out_dtype),  # hot features
-                        pltpu.VMEM((2, D), cold.dtype),
-                        pltpu.VMEM((2, D), hot.dtype),
-                        pltpu.SemaphoreType.DMA((2,)),
-                        pltpu.SemaphoreType.DMA((2,))],
-    )
-    kernel = _make_fused_front_end_kernel(
-        L, block_l, G, BB, has_weights=weights is not None,
-        has_scales=scales is not None, dedup=False)
-    return pl.pallas_call(
-        kernel, grid_spec=grid_spec,
-        out_shape=jax.ShapeDtypeStruct((B, P), out_dtype),
-        interpret=interpret,
-    )(*prefetch, cold, hot, x)
+        return jnp.zeros((B, (G + 1) * G // 2), out_dtype)
+    return _fused_call(cold, hot, x, rows, owned, is_hot, weights, scales,
+                       None, out_dtype, interpret, block_l, block_b,
+                       "interact")
 
 
 @functools.partial(jax.jit, static_argnames=("out_dtype", "interpret",
@@ -640,51 +707,14 @@ def fused_front_end_dedup_pallas(cold: jax.Array, hot: jax.Array,
     non-dedup kernel.
     """
     if interpret is None:
-        interpret = jax.default_backend() != "tpu"
+        interpret = _default_interpret()
     B, G, L = c_slots.shape
-    D = cold.shape[-1]
-    BB, block_l, tri, P = _fe_blocks(B, L, block_l, block_b, G)
     if B == 0 or L == 0 or G == 0:
-        return jnp.zeros((B, P), out_dtype)
-    U = c_unique.shape[0]
-
-    prefetch = [c_slots.astype(jnp.int32), h_slots.astype(jnp.int32),
-                owned.astype(jnp.int32), is_hot.astype(jnp.int32)]
-    if weights is not None:
-        prefetch.append(weights)
-    prefetch.append(c_unique.astype(jnp.int32))
-    prefetch.append(c_n.astype(jnp.int32).reshape(1))
-    if c_scales is not None:
-        prefetch.append(c_scales.astype(jnp.float32))
-    prefetch.append(h_unique.astype(jnp.int32))
-    prefetch.append(h_n.astype(jnp.int32).reshape(1))
-    prefetch.append(tri)
-
-    F = G + 1
-    grid_spec = pltpu.PrefetchScalarGridSpec(
-        num_scalar_prefetch=len(prefetch),
-        grid=(B // BB, G, pl.cdiv(L, block_l)),
-        in_specs=[pl.BlockSpec(memory_space=pltpu.ANY),    # cold stays in HBM
-                  pl.BlockSpec(memory_space=pltpu.ANY),    # hot stays in HBM
-                  pl.BlockSpec((BB, D), lambda bt, g, t, *p: (bt, 0))],
-        out_specs=pl.BlockSpec((BB, P), lambda bt, g, t, *p: (bt, 0)),
-        scratch_shapes=[pltpu.VMEM((U, D), out_dtype),     # cold row staging
-                        pltpu.VMEM((U, D), out_dtype),     # hot row staging
-                        pltpu.VMEM((BB * F, D), out_dtype),
-                        pltpu.VMEM((BB * F, D), out_dtype),
-                        pltpu.VMEM((2, D), cold.dtype),
-                        pltpu.VMEM((2, D), hot.dtype),
-                        pltpu.SemaphoreType.DMA((2,)),
-                        pltpu.SemaphoreType.DMA((2,))],
-    )
-    kernel = _make_fused_front_end_kernel(
-        L, block_l, G, BB, has_weights=weights is not None,
-        has_scales=c_scales is not None, dedup=True)
-    return pl.pallas_call(
-        kernel, grid_spec=grid_spec,
-        out_shape=jax.ShapeDtypeStruct((B, P), out_dtype),
-        interpret=interpret,
-    )(*prefetch, cold, hot, x)
+        return jnp.zeros((B, (G + 1) * G // 2), out_dtype)
+    plans = ((c_unique, c_slots, c_n, c_scales), (h_unique, h_slots, h_n))
+    return _fused_call(cold, hot, x, None, owned, is_hot, weights, None,
+                       plans, out_dtype, interpret, block_l, block_b,
+                       "interact")
 
 
 @functools.partial(jax.jit, static_argnames=("out_dtype", "interpret",
@@ -713,46 +743,12 @@ def fused_partial_pool_pallas(cold: jax.Array, hot: jax.Array, x: jax.Array,
     reduced tile.
     """
     if interpret is None:
-        interpret = jax.default_backend() != "tpu"
+        interpret = _default_interpret()
     B, G, L = rows.shape
-    D = cold.shape[-1]
-    BB, block_l, _, _ = _fe_blocks(B, L, block_l, block_b, G)
-    F = G + 1
     if B == 0 or L == 0 or G == 0:
-        zc = jnp.zeros((B, F, D), out_dtype)
-        return zc, zc.at[:, 0, :].set(x.astype(out_dtype))
-
-    prefetch = [rows.astype(jnp.int32), owned.astype(jnp.int32),
-                is_hot.astype(jnp.int32)]
-    if weights is not None:
-        prefetch.append(weights)
-    if scales is not None:
-        prefetch.append(scales.astype(jnp.float32))
-
-    tile_spec = pl.BlockSpec((BB, F, D), lambda bt, g, t, *p: (bt, 0, 0))
-    grid_spec = pltpu.PrefetchScalarGridSpec(
-        num_scalar_prefetch=len(prefetch),
-        grid=(B // BB, G, pl.cdiv(L, block_l)),
-        in_specs=[pl.BlockSpec(memory_space=pltpu.ANY),    # cold stays in HBM
-                  pl.BlockSpec(memory_space=pltpu.ANY),    # hot stays in HBM
-                  pl.BlockSpec((BB, D), lambda bt, g, t, *p: (bt, 0))],
-        out_specs=[tile_spec, tile_spec],
-        scratch_shapes=[pltpu.VMEM((BB * F, D), out_dtype),  # cold features
-                        pltpu.VMEM((BB * F, D), out_dtype),  # hot features
-                        pltpu.VMEM((2, D), cold.dtype),
-                        pltpu.VMEM((2, D), hot.dtype),
-                        pltpu.SemaphoreType.DMA((2,)),
-                        pltpu.SemaphoreType.DMA((2,))],
-    )
-    kernel = _make_fused_front_end_kernel(
-        L, block_l, G, BB, has_weights=weights is not None,
-        has_scales=scales is not None, dedup=False, emit="tiles")
-    return pl.pallas_call(
-        kernel, grid_spec=grid_spec,
-        out_shape=[jax.ShapeDtypeStruct((B, F, D), out_dtype),
-                   jax.ShapeDtypeStruct((B, F, D), out_dtype)],
-        interpret=interpret,
-    )(*prefetch, cold, hot, x)
+        return _empty_tiles(B, G + 1, cold.shape[-1], x, out_dtype)
+    return _fused_call(cold, hot, x, rows, owned, is_hot, weights, scales,
+                       None, out_dtype, interpret, block_l, block_b, "tiles")
 
 
 @functools.partial(jax.jit, static_argnames=("out_dtype", "interpret",
@@ -774,69 +770,25 @@ def fused_partial_pool_dedup_pallas(cold: jax.Array, hot: jax.Array,
     Bit-for-bit equal to the non-dedup tiles.
     """
     if interpret is None:
-        interpret = jax.default_backend() != "tpu"
+        interpret = _default_interpret()
     B, G, L = c_slots.shape
-    D = cold.shape[-1]
-    BB, block_l, _, _ = _fe_blocks(B, L, block_l, block_b, G)
-    F = G + 1
     if B == 0 or L == 0 or G == 0:
-        zc = jnp.zeros((B, F, D), out_dtype)
-        return zc, zc.at[:, 0, :].set(x.astype(out_dtype))
-    U = c_unique.shape[0]
-
-    prefetch = [c_slots.astype(jnp.int32), h_slots.astype(jnp.int32),
-                owned.astype(jnp.int32), is_hot.astype(jnp.int32)]
-    if weights is not None:
-        prefetch.append(weights)
-    prefetch.append(c_unique.astype(jnp.int32))
-    prefetch.append(c_n.astype(jnp.int32).reshape(1))
-    if c_scales is not None:
-        prefetch.append(c_scales.astype(jnp.float32))
-    prefetch.append(h_unique.astype(jnp.int32))
-    prefetch.append(h_n.astype(jnp.int32).reshape(1))
-
-    tile_spec = pl.BlockSpec((BB, F, D), lambda bt, g, t, *p: (bt, 0, 0))
-    grid_spec = pltpu.PrefetchScalarGridSpec(
-        num_scalar_prefetch=len(prefetch),
-        grid=(B // BB, G, pl.cdiv(L, block_l)),
-        in_specs=[pl.BlockSpec(memory_space=pltpu.ANY),    # cold stays in HBM
-                  pl.BlockSpec(memory_space=pltpu.ANY),    # hot stays in HBM
-                  pl.BlockSpec((BB, D), lambda bt, g, t, *p: (bt, 0))],
-        out_specs=[tile_spec, tile_spec],
-        scratch_shapes=[pltpu.VMEM((U, D), out_dtype),     # cold row staging
-                        pltpu.VMEM((U, D), out_dtype),     # hot row staging
-                        pltpu.VMEM((BB * F, D), out_dtype),
-                        pltpu.VMEM((BB * F, D), out_dtype),
-                        pltpu.VMEM((2, D), cold.dtype),
-                        pltpu.VMEM((2, D), hot.dtype),
-                        pltpu.SemaphoreType.DMA((2,)),
-                        pltpu.SemaphoreType.DMA((2,))],
-    )
-    kernel = _make_fused_front_end_kernel(
-        L, block_l, G, BB, has_weights=weights is not None,
-        has_scales=c_scales is not None, dedup=True, emit="tiles")
-    return pl.pallas_call(
-        kernel, grid_spec=grid_spec,
-        out_shape=[jax.ShapeDtypeStruct((B, F, D), out_dtype),
-                   jax.ShapeDtypeStruct((B, F, D), out_dtype)],
-        interpret=interpret,
-    )(*prefetch, cold, hot, x)
+        return _empty_tiles(B, G + 1, cold.shape[-1], x, out_dtype)
+    plans = ((c_unique, c_slots, c_n, c_scales), (h_unique, h_slots, h_n))
+    return _fused_call(cold, hot, x, None, owned, is_hot, weights, None,
+                       plans, out_dtype, interpret, block_l, block_b,
+                       "tiles")
 
 
-def _make_fused_resume_kernel(BB: int, F: int):
+def _make_fused_resume_kernel():
     """Phase-3 resume body: cold/hot add on the *reduced* tile, then the
     dot-interaction matmul + static triangle pack — the same op sequence
     the ``emit='interact'`` kernel runs on its resident staging, so the
     tp-sharded composition stays bit-for-bit against the one-shard fusion.
     """
 
-    def kernel(tri_ref, c_ref, h_ref, out_ref):
-        D = c_ref.shape[-1]
-        feats = (c_ref[...] + h_ref[...]).reshape(BB, F, D)
-        z = jax.lax.dot_general(
-            feats, feats, (((2,), (2,)), ((0,), (0,))),
-            preferred_element_type=out_ref.dtype)               # (BB, F, F)
-        out_ref[...] = jnp.take(z.reshape(BB, F * F), tri_ref[...], axis=1)
+    def kernel(c_ref, h_ref, out_ref):
+        out_ref[...] = _interact(c_ref[...] + h_ref[...], out_ref.dtype)
 
     return kernel
 
@@ -854,56 +806,18 @@ def fused_resume_pallas(part_c: jax.Array, part_h: jax.Array,
     concat'd features tensor through HBM.
     """
     if interpret is None:
-        interpret = jax.default_backend() != "tpu"
+        interpret = _default_interpret()
     B, F, D = part_c.shape
-    G = F - 1
-    BB, _, tri, P = _fe_blocks(B, 1, 1, block_b, G)
-    if B == 0 or G == 0:
+    P = F * (F - 1) // 2
+    if B == 0 or F == 1:
         return jnp.zeros((B, P), out_dtype)
-
-    tile_spec = pl.BlockSpec((BB, F, D), lambda bt, *p: (bt, 0, 0))
-    grid_spec = pltpu.PrefetchScalarGridSpec(
-        num_scalar_prefetch=1,
-        grid=(B // BB,),
+    Bp, BB = _bag_blocks(B, block_b)
+    tile_spec = pl.BlockSpec((BB, F, D), lambda bt: (bt, 0, 0))
+    out = pl.pallas_call(
+        _make_fused_resume_kernel(), grid=(Bp // BB,),
         in_specs=[tile_spec, tile_spec],
-        out_specs=pl.BlockSpec((BB, P), lambda bt, *p: (bt, 0)),
-    )
-    return pl.pallas_call(
-        _make_fused_resume_kernel(BB, F), grid_spec=grid_spec,
-        out_shape=jax.ShapeDtypeStruct((B, P), out_dtype),
+        out_specs=pl.BlockSpec((BB, P), lambda bt: (bt, 0)),
+        out_shape=jax.ShapeDtypeStruct((Bp, P), out_dtype),
         interpret=interpret,
-    )(tri, part_c, part_h)
-
-
-@functools.partial(jax.jit,
-                   static_argnames=("out_dtype", "interpret", "block_l"))
-def sls_pallas(table: jax.Array, indices: jax.Array,
-               weights: Optional[jax.Array] = None,
-               out_dtype=jnp.float32, interpret: bool = True,
-               block_l: int = 8) -> jax.Array:
-    """SLS via pl.pallas_call. indices: (B, L) int32 -> (B, D) pooled."""
-    return _sls_call(table, indices, None, weights, None, out_dtype,
-                     interpret, block_l)
-
-
-@functools.partial(jax.jit,
-                   static_argnames=("out_dtype", "interpret", "block_l"))
-def masked_sls_pallas(table: jax.Array, indices: jax.Array, owned: jax.Array,
-                      weights: Optional[jax.Array] = None,
-                      scales: Optional[jax.Array] = None,
-                      out_dtype=jnp.float32, interpret: bool = True,
-                      block_l: int = 8) -> jax.Array:
-    """Masked partial SLS: out[b] = sum_l owned[b,l]*w[b,l]*table[idx[b,l]].
-
-    The per-shard operator of the PIFS engine: ``owned`` marks the pooling
-    entries whose rows live on this shard; everything else contributes zero
-    (and its gather is remapped to row 0, which must exist).
-
-    Optional ``scales`` (B, L): per-entry dequant scales for a quantized
-    (int8) ``table``.  Each DMA'd row is dequantized in VMEM
-    (``float(row) * scale``) right before the weighted accumulate — the
-    tiered-precision store's fused-dequant datapath (oracle:
-    ``kernels/ref.py:masked_sls_quant_ref``).
-    """
-    return _sls_call(table, indices, owned, weights, scales, out_dtype,
-                     interpret, block_l)
+    )(_pad_rows(part_c, Bp), _pad_rows(part_h, Bp))
+    return out[:B]

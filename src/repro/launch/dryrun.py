@@ -1,8 +1,12 @@
 import os
 os.environ["XLA_FLAGS"] = "--xla_force_host_platform_device_count=512"
+os.environ["JAX_PLATFORMS"] = "cpu"
 
-# NOTE: the two lines above MUST precede every other import (jax locks the
-# device count on first init), which is why the docstring sits below them.
+# NOTE: the lines above MUST precede every other import (jax locks the
+# device count and platform on first init), which is why the docstring sits
+# below them.  The dry-run is a CPU compile study: pinning the platform
+# keeps this process and every cell subprocess (which inherits os.environ)
+# off any attached accelerator, which only one process may hold.
 
 _DOC = """Multi-pod dry-run: lower + compile every (architecture x
 input-shape) cell on the production meshes and extract the roofline terms.

@@ -7,7 +7,7 @@ and smoke tests must keep seeing 1 device.
 """
 from __future__ import annotations
 
-import jax
+from repro.distributed.sharding import make_mesh
 
 
 def make_production_mesh(*, multi_pod: bool = False):
@@ -15,9 +15,9 @@ def make_production_mesh(*, multi_pod: bool = False):
     pure-DP "pod" axis: (pod=2, data=16, model=16) = 512 chips."""
     shape = (2, 16, 16) if multi_pod else (16, 16)
     axes = ("pod", "data", "model") if multi_pod else ("data", "model")
-    return jax.make_mesh(shape, axes)
+    return make_mesh(shape, axes)
 
 
 def make_test_mesh(n_devices: int = 8, tp: int = 4):
     """Small mesh for CPU smoke tests (same logical axes)."""
-    return jax.make_mesh((n_devices // tp, tp), ("data", "model"))
+    return make_mesh((n_devices // tp, tp), ("data", "model"))

@@ -15,9 +15,10 @@ placement-invariant lookups, so no query ever blocks on page management.
 from __future__ import annotations
 
 import argparse
-from typing import Dict, Optional, Tuple
+from typing import Callable, Dict, Optional, Tuple
 
 import jax
+import numpy as np
 
 from repro.configs import get_config, reduced
 from repro.runtime.fault_tolerance import StragglerWatchdog
@@ -34,6 +35,7 @@ from repro.serving import (BatcherConfig, BindingExecutor, BreakerConfig,
                            update_stream)
 from repro.checkpoint.checkpointer import Checkpointer
 from repro.checkpoint.wal import WriteAheadLog
+from repro.launch import compile_cache
 from repro.launch.mesh import make_test_mesh
 from repro.serving.request import ArrivalConfig
 
@@ -90,6 +92,7 @@ def serve_offered_load(cfg, mesh, load: LoadConfig, *, mode: str = "pifs",
                        mesh_faults: bool = False, prefer_tp: int = 2,
                        fault_seed: int = 13,
                        scrub: bool = False, scrub_pages_per_cycle: int = 8,
+                       after_run: Optional[Callable] = None,
                        ) -> Dict[str, object]:
     """End-to-end: bind, warm every bucket, serve the stream, and report
     metrics + the steady-state retrace count (must be 0).  The engine's
@@ -120,7 +123,12 @@ def serve_offered_load(cfg, mesh, load: LoadConfig, *, mode: str = "pifs",
     runtime's maintenance seam auditing ``scrub_pages_per_cycle`` pages
     per micro-batch and repairing any diverged page surgically (snapshot
     page slice + filtered WAL replay).  The summary carries the scrub
-    report (``scrub_run``: coverage, detections, per-page repair MTTR)."""
+    report (``scrub_run``: coverage, detections, per-page repair MTTR).
+
+    ``after_run(binding)``, when given, is called once the stream has
+    drained, still inside the mesh context — for checks on the served
+    binding (probe scores, the lowered step) that must not count toward
+    the steady window."""
     runtime, binding = build_serving(
         cfg, mesh, mode=mode, impl=impl, block_l=block_l, batcher=batcher,
         batch_sizes=batch_sizes, poolings=load.poolings, slo_ms=load.slo_ms,
@@ -201,8 +209,14 @@ def serve_offered_load(cfg, mesh, load: LoadConfig, *, mode: str = "pifs",
         else:
             source = OpenLoopSource(reqs)
         summary = runtime.run(source)
-    stats = binding.plan_stats()
+        stats = binding.plan_stats()
+        if after_run is not None:
+            after_run(binding)
     summary["steady_traces"] = stats["traces"]
+    service_ms = [b.service_s * 1e3 for b in runtime.metrics.batches]
+    if service_ms:
+        summary["service_p50_ms"] = float(np.percentile(service_ms, 50.0))
+        summary["service_p99_ms"] = float(np.percentile(service_ms, 99.0))
     if mesh_faults:
         summary["remeshes"] = binding.remeshes
         summary["faults_fired"] = runtime.executor.report()
@@ -289,6 +303,7 @@ def main() -> None:
     ap.add_argument("--seed", type=int, default=0)
     ap.add_argument("--full", action="store_true")
     args = ap.parse_args()
+    compile_cache.enable()
 
     cfg = get_config(args.arch)
     if not args.full:

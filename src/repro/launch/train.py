@@ -20,6 +20,7 @@ from repro.checkpoint.checkpointer import Checkpointer
 from repro.configs import base as cfgs
 from repro.configs import get_config, reduced
 from repro.data import synth
+from repro.launch import compile_cache
 from repro.launch.mesh import make_test_mesh
 from repro.models import dlrm as dlrm_mod
 from repro.models import gnn as gnn_mod
@@ -145,6 +146,7 @@ def main() -> None:
                     help="full config (real hardware)")
     ap.add_argument("--ckpt-dir")
     args = ap.parse_args()
+    compile_cache.enable()
 
     cfg = get_config(args.arch)
     if not args.full:

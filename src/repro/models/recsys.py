@@ -397,12 +397,8 @@ def make_train_step(cfg: RecConfig, engine: PIFSEmbeddingEngine,
             params, emb_state.cold, emb_state.hot)
         gp, gcold, ghot = grads
         new_params, new_opt = optimizer.update(gp, opt_state, params)
-        emb_params = {"cold": emb_state.cold, "hot": emb_state.hot}
-        emb_grads = {"cold": gcold, "hot": ghot}
-        new_emb, new_emb_opt = emb_optimizer.update(
-            emb_grads, emb_opt_state, emb_params)
-        new_state = dataclasses.replace(
-            emb_state, cold=new_emb["cold"], hot=new_emb["hot"])
+        new_state, new_emb_opt = engine.optimizer_step(
+            emb_optimizer, emb_state, gcold, ghot, emb_opt_state)
         return new_params, new_state, new_opt, new_emb_opt, {"loss": loss}
     return step
 

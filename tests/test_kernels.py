@@ -6,7 +6,14 @@ import pytest
 
 from repro.kernels import ops, ref
 from repro.kernels.interaction import dot_interaction_pallas
-from repro.kernels.sls import sls_pallas
+from repro.kernels.sls import LANES, sls_pallas
+
+
+def _pad_lanes(a):
+    """Zero-pad the minor dim to the lane boundary, as the engine stores
+    its rows (``PagingConfig.store_dim``)."""
+    pad = -a.shape[-1] % LANES
+    return jnp.pad(a, [(0, 0)] * (a.ndim - 1) + [(0, pad)])
 
 
 @pytest.mark.parametrize("B,L,V,D", [
@@ -249,17 +256,33 @@ def test_sls_zero_length_bags():
 
 @pytest.mark.parametrize("D", [16, 100, 130])
 def test_sls_lane_padding_is_transparent(D):
-    """Forcing 128-lane padding must not change results or shapes."""
+    """Storing rows 128-lane padded must not change the logical lanes, and
+    the pad lanes pool to exact zeros."""
     k1, k2, k3 = jax.random.split(jax.random.PRNGKey(D), 3)
     table = jax.random.normal(k1, (64, D))
     idx = jax.random.randint(k2, (5, 4), 0, 64).astype(jnp.int32)
     owned = jax.random.bernoulli(k3, 0.7, (5, 4))
-    padded = ops.pad_to_lanes(table, pad_lanes=True)
-    assert padded.shape[1] % ops.LANES == 0 or D % ops.LANES == 0
-    a = ops.masked_sls(table, idx, owned, interpret=True, pad_lanes=True)
-    b = ops.masked_sls(table, idx, owned, interpret=True, pad_lanes=False)
-    assert a.shape == b.shape == (5, D)
-    np.testing.assert_array_equal(np.asarray(a), np.asarray(b))
+    padded = _pad_lanes(table)
+    assert padded.shape[1] % LANES == 0
+    a = ops.masked_sls(padded, idx, owned, interpret=True)
+    b = ops.masked_sls(table, idx, owned, interpret=True)
+    assert a.shape == (5, padded.shape[1]) and b.shape == (5, D)
+    np.testing.assert_array_equal(np.asarray(a[:, :D]), np.asarray(b))
+    np.testing.assert_array_equal(np.asarray(a[:, D:]), 0.0)
+
+
+@pytest.mark.parametrize("D", [64, 130])
+def test_compiled_kernels_refuse_misaligned_rows(D):
+    """Off interpret mode a row width off the 128-lane tile is refused
+    before any kernel is built (the chip DMAs rows in whole lane tiles)."""
+    table = jnp.zeros((64, D))
+    idx = jnp.zeros((8, 4), jnp.int32)
+    with pytest.raises(ValueError, match="lanes"):
+        ops.masked_sls(table, idx, idx >= 0, interpret=False)
+    rows = jnp.zeros((8, 2, 4), jnp.int32)
+    with pytest.raises(ValueError, match="lanes"):
+        ops.fused_front_end(table, table, jnp.zeros((8, D)), rows,
+                            rows >= 0, rows < 0, interpret=False)
 
 
 # ---------------------------------------------------------------------------
@@ -383,10 +406,10 @@ def test_fused_front_end_lane_padding_is_transparent():
     B, G, L, V, D = 4, 2, 5, 64, 24
     cold, hot, x, rows, owned, is_hot, w, _ = _fe_inputs(
         B, G, L, V, D, True, False)
-    a = ops.fused_front_end(cold, hot, x, rows, owned, is_hot, w,
-                            interpret=True, pad_lanes=True)
+    a = ops.fused_front_end(_pad_lanes(cold), _pad_lanes(hot), _pad_lanes(x),
+                            rows, owned, is_hot, w, interpret=True)
     b = ops.fused_front_end(cold, hot, x, rows, owned, is_hot, w,
-                            interpret=True, pad_lanes=False)
+                            interpret=True)
     assert a.shape == b.shape
     np.testing.assert_array_equal(np.asarray(a), np.asarray(b))
 
@@ -479,21 +502,23 @@ def test_fused_partial_pool_simulated_psum_bit_exact():
 
 
 def test_fused_partial_pool_lane_padding_is_transparent():
-    """D=24 is not lane-aligned: the partial tiles are sliced back to D
-    (the collective must ship exactly B*F*D elements) and the resume
-    re-pads — no output bit changes anywhere in the composition."""
+    """D=24 is not lane-aligned: pooling from padded operands gives the
+    unpadded partial tiles plus zero pad lanes (the engine slices them off
+    before the collective, which ships exactly B*F*D elements), and the
+    resume on padded tiles changes no output bit."""
     B, G, L, V, D = 4, 2, 5, 64, 24
     cold, hot, x, rows, owned, is_hot, w, _ = _fe_inputs(
         B, G, L, V, D, True, False)
-    pc_a, ph_a = ops.fused_partial_pool(cold, hot, x, rows, owned, is_hot, w,
-                                        interpret=True, pad_lanes=True)
+    pc_a, ph_a = ops.fused_partial_pool(
+        _pad_lanes(cold), _pad_lanes(hot), _pad_lanes(x), rows, owned,
+        is_hot, w, interpret=True)
     pc_b, ph_b = ops.fused_partial_pool(cold, hot, x, rows, owned, is_hot, w,
-                                        interpret=True, pad_lanes=False)
-    assert pc_a.shape == pc_b.shape == (B, G + 1, D)
-    np.testing.assert_array_equal(np.asarray(pc_a), np.asarray(pc_b))
-    np.testing.assert_array_equal(np.asarray(ph_a), np.asarray(ph_b))
-    a = ops.fused_resume(pc_a, ph_a, interpret=True, pad_lanes=True)
-    b = ops.fused_resume(pc_b, ph_b, interpret=True, pad_lanes=False)
+                                        interpret=True)
+    assert pc_a.shape == (B, G + 1, LANES) and pc_b.shape == (B, G + 1, D)
+    np.testing.assert_array_equal(np.asarray(pc_a), _pad_lanes(pc_b))
+    np.testing.assert_array_equal(np.asarray(ph_a), _pad_lanes(ph_b))
+    a = ops.fused_resume(pc_a, ph_a, interpret=True)
+    b = ops.fused_resume(pc_b, ph_b, interpret=True)
     np.testing.assert_array_equal(np.asarray(a), np.asarray(b))
 
 

@@ -10,7 +10,9 @@ import pytest
 from repro.core import sls as sls_ops
 from repro.core.paging import PagingConfig, initial_page_table
 from repro.core.pifs import PIFSEmbeddingEngine, engine_for_tables
-from repro.core.planner import PlannerConfig, plan, shard_loads
+from repro.core.paging import HOT_SHARD
+from repro.core.planner import (PlannerConfig, needs_migration, plan,
+                                shard_loads)
 
 
 @pytest.fixture()
@@ -111,6 +113,73 @@ def test_gradients_flow_through_lookup(engine, mesh):
     # every accessed row contributes gradient 1 per accessed element
     total = float(np.asarray(gc).sum() + np.asarray(gh).sum())
     assert total == pytest.approx(4 * 2 * 4 * 16, rel=1e-3)
+
+
+def _plan_loop_reference(cfg, table, counts, pcfg):
+    """The planner's hot-set hysteresis and sticky placement as the
+    per-page Python loops they replaced (placement only, no stats)."""
+    counts = np.asarray(counts, np.float64)
+    old_shard = np.asarray(table.page_to_shard)
+    old_slot = np.asarray(table.page_to_slot)
+    order = np.argsort(-counts, kind="stable")
+    resident_hot = set(np.nonzero(old_shard == HOT_SHARD)[0].tolist())
+    hot_set = set(order[: cfg.hot_pages].tolist())
+    if pcfg.sticky and resident_hot:
+        hot_set = set(resident_hot)
+        for c in [p for p in order[: 4 * cfg.hot_pages]
+                  if p not in resident_hot]:
+            if len(hot_set) < cfg.hot_pages:
+                hot_set.add(int(c))
+                continue
+            victim = min(hot_set, key=lambda p: counts[p])
+            if counts[c] > counts[victim] * (1.0 + pcfg.cold_age_threshold):
+                hot_set.discard(victim)
+                hot_set.add(int(c))
+    hot_list = sorted(hot_set, key=lambda p: -counts[p])[: cfg.hot_pages]
+    hot_mask = np.zeros(cfg.num_pages, bool)
+    hot_mask[hot_list] = True
+    new_shard = np.full(cfg.num_pages, HOT_SHARD, np.int32)
+    new_slot = np.zeros(cfg.num_pages, np.int32)
+    new_slot[hot_list] = np.arange(len(hot_list), dtype=np.int32)
+    cold_pages = np.nonzero(~hot_mask)[0]
+    loads = np.zeros(cfg.n_shards)
+    fill = np.zeros(cfg.n_shards, np.int64)
+    unplaced = cold_pages
+    if pcfg.sticky and not needs_migration(cfg, table, counts, pcfg):
+        for p in cold_pages:
+            s = old_shard[p]
+            if s != HOT_SHARD:
+                new_shard[p], new_slot[p] = s, old_slot[p]
+                loads[s] += counts[p]
+                fill[s] = max(fill[s], old_slot[p] + 1)
+        unplaced = cold_pages[new_shard[cold_pages] == HOT_SHARD]
+    for p in unplaced[np.argsort(-counts[unplaced], kind="stable")]:
+        cands = np.nonzero(fill < cfg.pages_per_shard)[0]
+        s = cands[np.argmin(loads[cands])]
+        new_shard[p], new_slot[p] = s, fill[s]
+        fill[s] += 1
+        loads[s] += counts[p]
+    return new_shard, new_slot
+
+
+@pytest.mark.parametrize("skew", [1.05, 1.6])
+def test_planner_matches_loop_reference(skew):
+    """Repeated replans (hot set resident, sticky and full LPT branches)
+    place every page exactly as the per-page loops did; counts are
+    distinct, so no tie decides a victim."""
+    cfg = PagingConfig(total_rows=8192, dim=16, n_shards=4, hot_fraction=0.05)
+    table = initial_page_table(cfg)
+    rng = np.random.default_rng(3)
+    for _ in range(4):
+        counts = rng.zipf(skew, cfg.num_pages) + rng.random(cfg.num_pages)
+        new_table, _ = plan(cfg, table, counts, PlannerConfig())
+        want_shard, want_slot = _plan_loop_reference(cfg, table, counts,
+                                                     PlannerConfig())
+        np.testing.assert_array_equal(np.asarray(new_table.page_to_shard),
+                                      want_shard)
+        np.testing.assert_array_equal(np.asarray(new_table.page_to_slot),
+                                      want_slot)
+        table = new_table
 
 
 def test_planner_balances_loads():
