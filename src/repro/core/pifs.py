@@ -37,6 +37,7 @@ from repro.core.paging import (HOT_SHARD, PageTable, PagingConfig,
                                initial_page_table, locate,
                                placement_gather_indices)
 from repro.core.planner import PlannerConfig, plan
+from repro.core.spans import SPANS
 from repro.distributed.sharding import MeshAxes, axes_for, shard_map
 
 
@@ -686,10 +687,11 @@ class PIFSEmbeddingEngine:
                                         idx, wloc, mode=mode,
                                         combine="psum", impl=impl,
                                         block_l=block_l, dedup=dedup)
-            feats = jnp.concatenate([x[:, None, :], pooled], axis=1)
             from repro.kernels import ops as kernel_ops
-            return kernel_ops.dot_interaction(feats, impl=impl,
-                                              block_b=block_b)
+            with jax.named_scope("interaction"):
+                feats = jnp.concatenate([x[:, None, :], pooled], axis=1)
+                return kernel_ops.dot_interaction(feats, impl=impl,
+                                                  block_b=block_b)
 
         f = shard_map(
             block, mesh=mesh,
@@ -719,11 +721,13 @@ class PIFSEmbeddingEngine:
         owned = shard == jax.lax.axis_index(axes.tp)
         is_hot = shard == HOT_SHARD
         scale = scales[page] if self.quantized else None
-        return sls_ops.fused_front_end_dense(
-            cold, hot, self._pad_lanes(x), local_row, owned, is_hot,
-            weights=weights,
-            scales=scale, impl=impl, block_l=block_l, block_b=block_b,
-            dedup=dedup, out_dtype=jnp.float32)
+        # one kernel pools and interacts: its time is all under "embed"
+        with jax.named_scope("embed"):
+            return sls_ops.fused_front_end_dense(
+                cold, hot, self._pad_lanes(x), local_row, owned, is_hot,
+                weights=weights,
+                scales=scale, impl=impl, block_l=block_l, block_b=block_b,
+                dedup=dedup, out_dtype=jnp.float32)
 
     def _interact_block_fused_tp(self, cold, hot, scales, p2s, p2slot, idx,
                                  x, weights, *, impl: str, block_l: int,
@@ -747,15 +751,18 @@ class PIFSEmbeddingEngine:
         owned = shard == jax.lax.axis_index(axes.tp)
         is_hot = shard == HOT_SHARD
         scale = scales[page] if self.quantized else None
-        part_c, part_h = sls_ops.fused_partial_pool_dense(
-            cold, hot, self._pad_lanes(x), local_row, owned, is_hot,
-            weights=weights, scales=scale, impl=impl, block_l=block_l,
-            block_b=block_b, dedup=dedup, out_dtype=jnp.float32)
-        # only the logical lanes cross the fabric
-        part_c, part_h = self._logical(part_c), self._logical(part_h)
-        reduced = jax.lax.psum(part_c, axes.tp)
-        return sls_ops.fused_resume_dense(reduced, part_h, impl=impl,
-                                          block_b=block_b)
+        with jax.named_scope("embed"):
+            part_c, part_h = sls_ops.fused_partial_pool_dense(
+                cold, hot, self._pad_lanes(x), local_row, owned, is_hot,
+                weights=weights, scales=scale, impl=impl, block_l=block_l,
+                block_b=block_b, dedup=dedup, out_dtype=jnp.float32)
+            # only the logical lanes cross the fabric
+            part_c, part_h = self._logical(part_c), self._logical(part_h)
+        with jax.named_scope("combine"):
+            reduced = jax.lax.psum(part_c, axes.tp)
+        with jax.named_scope("interaction"):
+            return sls_ops.fused_resume_dense(reduced, part_h, impl=impl,
+                                              block_b=block_b)
 
     # ------------------------------------------------- compiled-lookup plans
     def _resolve_dedup(self, key, dedup: str, state: EngineState,
@@ -1007,26 +1014,28 @@ class PIFSEmbeddingEngine:
         bags = idx.reshape(nbags, L)
         wbags = None if weights is None else weights.reshape(nbags, L)
 
-        ps = c.page_size
-        page = bags // ps
-        offset = bags % ps
-        shard = p2s[page]
-        local_row = p2slot[page] * ps + offset                  # (nbags, L)
         my = jax.lax.axis_index(tp)
-        owned = shard == my
-        is_hot = shard == HOT_SHARD
-        # per-entry dequant scales (page-aligned addressing: the scale of an
-        # entry is its *global page's* scale) — an O(bags*L) scalar gather;
-        # the (rows, D)-sized fp32 cold table is never materialized
-        scale_be = scales[page] if self.quantized else None     # (nbags, L)
+        with jax.named_scope("embed"):
+            ps = c.page_size
+            page = bags // ps
+            offset = bags % ps
+            shard = p2s[page]
+            local_row = p2slot[page] * ps + offset              # (nbags, L)
+            owned = shard == my
+            is_hot = shard == HOT_SHARD
+            # per-entry dequant scales (page-aligned addressing: the scale
+            # of an entry is its *global page's* scale) — an O(bags*L)
+            # scalar gather; the (rows, D)-sized fp32 cold table is never
+            # materialized
+            scale_be = scales[page] if self.quantized else None  # (nbags, L)
 
-        # ---- hot tier: replicated, zero-communication ----
-        # dedup applies here too: hot hits are local-HBM reads, and under
-        # zipfian traffic the hot tier is where duplicates concentrate
-        # pooled rows leave without pad lanes
-        hot_out = self._logical(sls_ops.masked_partial_sls_dense(
-            hot, local_row, is_hot, wbags, impl=impl,
-            block_l=block_l, dedup=dedup))                      # (nbags, D)
+            # ---- hot tier: replicated, zero-communication ----
+            # dedup applies here too: hot hits are local-HBM reads, and
+            # under zipfian traffic the hot tier is where duplicates
+            # concentrate; pooled rows leave without pad lanes
+            hot_out = self._logical(sls_ops.masked_partial_sls_dense(
+                hot, local_row, is_hot, wbags, impl=impl,
+                block_l=block_l, dedup=dedup))                  # (nbags, D)
 
         if tiers == "hot_only":
             # brown-out rung: serve the replicated hot tier only — cold
@@ -1062,7 +1071,8 @@ class PIFSEmbeddingEngine:
                     rows, scale_be.reshape(-1)[:, None])
             if wbags is not None:
                 rows = rows * wbags.reshape(-1)[:, None].astype(rows.dtype)
-            rows = jax.lax.psum(rows, tp)                        # (b*G*L, D)!
+            with jax.named_scope("combine"):
+                rows = jax.lax.psum(rows, tp)                    # (b*G*L, D)!
             cold_out = jax.ops.segment_sum(rows, seg, num_segments=nbags)
             out = cold_out + hot_out
             if combine == "psum_scatter":
@@ -1077,21 +1087,24 @@ class PIFSEmbeddingEngine:
             return out.reshape(b, G, -1)
 
         # pifs / beacon: partial SLS near the data, pooled partials only
-        cold_part = self._logical(sls_ops.masked_partial_sls_dense(
-            cold, local_row, owned, wbags, impl=impl,
-            block_l=block_l, scales=scale_be,
-            out_dtype=jnp.float32 if self.quantized else None,
-            dedup=dedup))                                        # (nbags, D)
+        with jax.named_scope("embed"):
+            cold_part = self._logical(sls_ops.masked_partial_sls_dense(
+                cold, local_row, owned, wbags, impl=impl,
+                block_l=block_l, scales=scale_be,
+                out_dtype=jnp.float32 if self.quantized else None,
+                dedup=dedup))                                    # (nbags, D)
         if combine == "psum":
-            cold_sum = jax.lax.psum(cold_part, tp)
+            with jax.named_scope("combine"):
+                cold_sum = jax.lax.psum(cold_part, tp)
             return (cold_sum + hot_out).reshape(b, G, -1)
         # psum_scatter over the bag axis: each tp shard keeps its bag slice
         tp_size = axes.tp_size(self.mesh)
         if nbags % tp_size:
             raise ValueError(f"bags ({nbags}) must divide tp ({tp_size}) "
                              "for psum_scatter combine")
-        cold_sc = jax.lax.psum_scatter(cold_part, tp, scatter_dimension=0,
-                                       tiled=True)               # (nbags/tp, D)
+        with jax.named_scope("combine"):
+            cold_sc = jax.lax.psum_scatter(cold_part, tp, scatter_dimension=0,
+                                           tiled=True)           # (nbags/tp, D)
         hot_slice = jax.lax.dynamic_slice_in_dim(
             hot_out, my * (nbags // tp_size), nbags // tp_size, 0)
         out = cold_sc + hot_slice
@@ -1116,13 +1129,14 @@ class PIFSEmbeddingEngine:
             w_specs = (idx_spec,) if weights is not None else ()
 
             def block(counts, idx, *w):
-                page = idx.reshape(-1) // c.page_size
-                inc = (jnp.where(w[0].reshape(-1) != 0, 1.0, 0.0) if w
-                       else 1.0)
-                local = jnp.zeros_like(counts).at[page].add(inc)
-                if dp:
-                    local = jax.lax.psum(local, dp)
-                return counts + local
+                with jax.named_scope("observe"):
+                    page = idx.reshape(-1) // c.page_size
+                    inc = (jnp.where(w[0].reshape(-1) != 0, 1.0, 0.0) if w
+                           else 1.0)
+                    local = jnp.zeros_like(counts).at[page].add(inc)
+                    if dp:
+                        local = jax.lax.psum(local, dp)
+                    return counts + local
 
             f = jax.jit(shard_map(block, mesh=self.mesh,
                                   in_specs=(P(), idx_spec) + w_specs,
@@ -1649,9 +1663,11 @@ class ServeBinding:
         # per-bucket duplicate-access accounting, fed by observe() on the
         # maintenance path (never the timed service path): bucket index
         # shape -> accumulated entries / unique rows over observed batches.
-        # The probe is a host-side numpy replay — tens of microseconds per
-        # observed batch at serving shapes; ``track_dedup=False`` disables
-        # it for deployments that do not want the maintenance-path cost.
+        # The probe copies the page tables to the host and replays the
+        # batch in numpy: about 1.5 ms per observed 512-row RMC3 batch on
+        # one TPU v5e, 7.2 ms for RMC4 widths x 32 tables on four;
+        # ``track_dedup=False`` disables it for deployments that do not
+        # want the maintenance-path cost.
         self.track_dedup = track_dedup
         self.dedup_stats: dict = {}
         # named serve-step variants (brown-out rungs); "full" is the
@@ -1707,25 +1723,36 @@ class ServeBinding:
         self.active = label if label in self.steps else "full"
 
     def execute(self, batch: dict):
-        if self.validate_ids and self.idx_key and self.idx_key in batch:
-            # the serve step is jitted: the OOB check must see the concrete
-            # host batch, before tracing swallows it
-            self.engine._check_ids(np.asarray(batch[self.idx_key]))
-        jb = {k: jnp.asarray(v) for k, v in batch.items()}
-        out = self.steps[self.active](self.params, self.state, jb)
-        jax.block_until_ready(out)
-        if self.scrub_scores:
-            scores = np.asarray(out)
-            finite = np.isfinite(scores)
-            self.last_poisoned = int(scores.size - finite.sum())
-            if self.last_poisoned:
-                self.poisoned_rows += self.last_poisoned
-                self.poisoned_batches += 1
-                out = jnp.where(jnp.asarray(finite), out,
-                                jnp.zeros_like(out))
+        """Run one bucket-shaped batch and block until the device is done.
+
+        Spans (``repro.core.spans``): ``serve.execute`` over ``serve.stage``
+        (id check, host to device of every entry), ``serve.dispatch`` (the
+        jitted step's call) and ``serve.block``."""
+        SPANS.refresh()
+        with SPANS.span("serve.execute"):
+            with SPANS.span("serve.stage"):
+                if (self.validate_ids and self.idx_key
+                        and self.idx_key in batch):
+                    # the serve step is jitted: the OOB check must see the
+                    # concrete host batch, before tracing swallows it
+                    self.engine._check_ids(np.asarray(batch[self.idx_key]))
+                jb = {k: jnp.asarray(v) for k, v in batch.items()}
+            with SPANS.span("serve.dispatch"):
+                out = self.steps[self.active](self.params, self.state, jb)
+            with SPANS.span("serve.block"):
+                jax.block_until_ready(out)
+            if self.scrub_scores:
+                scores = np.asarray(out)
+                finite = np.isfinite(scores)
+                self.last_poisoned = int(scores.size - finite.sum())
+                if self.last_poisoned:
+                    self.poisoned_rows += self.last_poisoned
+                    self.poisoned_batches += 1
+                    out = jnp.where(jnp.asarray(finite), out,
+                                    jnp.zeros_like(out))
+                return out
+            self.last_poisoned = 0
             return out
-        self.last_poisoned = 0
-        return out
 
     # ------------------------------------------------------------ integrity
     def attach_integrity(self, ledger=None, chunk: int = 64) -> None:
@@ -2026,28 +2053,34 @@ class ServeBinding:
         return n
 
     def observe(self, batch: dict) -> None:
+        """Fold one batch into the page-access counts (span
+        ``observe.count``), then, with ``track_dedup``, into the per-bucket
+        duplicate-factor probe (span ``observe.probe``)."""
         if self.idx_key and self.idx_key in batch:
             w = batch.get("weights")
-            new = self.engine.observe(
-                self.state, jnp.asarray(batch[self.idx_key]),
-                weights=None if w is None else jnp.asarray(w))
-            # block here so the profiler update is charged to maintenance,
-            # not leaked into the next micro-batch's measured service time
-            jax.block_until_ready(new.counts)
-            self.state = new
+            with SPANS.span("observe.count"):
+                new = self.engine.observe(
+                    self.state, jnp.asarray(batch[self.idx_key]),
+                    weights=None if w is None else jnp.asarray(w))
+                # block here so the profiler update is charged to
+                # maintenance, not leaked into the next micro-batch's
+                # measured service time
+                jax.block_until_ready(new.counts)
+                self.state = new
             if not self.track_dedup:
                 return
             # dedup probe rides the same maintenance cadence: the measured
             # per-bucket duplicate factor makes serving-side bytes wins
             # attributable without touching the timed service path
-            d = self.engine.dedup_factor(
-                self.state, batch[self.idx_key], weights=w)
-            key = tuple(np.asarray(batch[self.idx_key]).shape)
-            rec = self.dedup_stats.setdefault(
-                key, {"batches": 0, "entries": 0, "unique_rows": 0})
-            rec["batches"] += 1
-            rec["entries"] += d["entries"]
-            rec["unique_rows"] += d["unique_rows"]
+            with SPANS.span("observe.probe"):
+                d = self.engine.dedup_factor(
+                    self.state, batch[self.idx_key], weights=w)
+                key = tuple(np.asarray(batch[self.idx_key]).shape)
+                rec = self.dedup_stats.setdefault(
+                    key, {"batches": 0, "entries": 0, "unique_rows": 0})
+                rec["batches"] += 1
+                rec["entries"] += d["entries"]
+                rec["unique_rows"] += d["unique_rows"]
 
     def dedup_report(self) -> dict:
         """Measured per-bucket duplicate-access factors (from the observe

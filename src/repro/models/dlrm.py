@@ -96,10 +96,13 @@ def forward(params: dict, engine: PIFSEmbeddingEngine, state,
         front_end = "split"                    # fused path is all-tiers only
     dense, idx = batch["dense"], batch["indices"]
     B = dense.shape[0]
-    x_bot = mlp_apply(params["bottom"], dense, len(cfg.bottom_mlp),
-                      final_act=True)
-    if "bot_proj" in params:
-        x_bot = x_bot @ params["bot_proj"]                  # (B, d)
+    # stage names (bottom_mlp, embed, combine, interaction, top_mlp) tie
+    # the step's device time to its stages in a profile
+    with jax.named_scope("bottom_mlp"):
+        x_bot = mlp_apply(params["bottom"], dense, len(cfg.bottom_mlp),
+                          final_act=True)
+        if "bot_proj" in params:
+            x_bot = x_bot @ params["bot_proj"]              # (B, d)
     # dense towers use the full (dp x tp) mesh, not just dp (see
     # recsys._constrain_full_batch)
     from repro.models.recsys import _constrain_full_batch
@@ -113,11 +116,14 @@ def forward(params: dict, engine: PIFSEmbeddingEngine, state,
                                mode=mode, impl=impl, block_l=block_l,
                                dedup=dedup, tiers=tiers)    # (B, T, d)
         pooled = _constrain_full_batch(pooled, engine)
-        feats = jnp.concatenate([x_bot[:, None, :], pooled],
-                                axis=1)                     # (B, F, d)
-        inter = kernel_ops.dot_interaction(feats, impl=interaction_impl)
-    z = jnp.concatenate([x_bot, inter], axis=-1)
-    logit = mlp_apply(params["top"], z, len(cfg.top_mlp))
+        with jax.named_scope("interaction"):
+            feats = jnp.concatenate([x_bot[:, None, :], pooled],
+                                    axis=1)                 # (B, F, d)
+            inter = kernel_ops.dot_interaction(feats,
+                                               impl=interaction_impl)
+    with jax.named_scope("top_mlp"):
+        z = jnp.concatenate([x_bot, inter], axis=-1)
+        logit = mlp_apply(params["top"], z, len(cfg.top_mlp))
     return logit[:, 0]
 
 

@@ -45,6 +45,7 @@ from typing import Dict, List, Optional, Sequence, Tuple
 
 import numpy as np
 
+from repro.core.spans import SPANS
 from repro.serving.request import Request
 
 
@@ -154,31 +155,33 @@ class DynamicBatcher:
     def decide(self, now: float, queued: Sequence[Request],
                next_arrival: Optional[float],
                service: ServiceModel) -> Decision:
-        if not queued:
-            return None
-        b_max = self.cfg.batch_sizes[-1]
-        cand = queued[:b_max]
-        bucket = Bucket(self._batch_size(len(cand)),
-                        self._pooling_level(cand))
-        if len(cand) >= b_max:
-            return Flush(bucket, b_max)
-        head = cand[0]
-        flush_by = (head.deadline_s - service.estimate(bucket)
-                    - self.cfg.safety_ms * 1e-3)
-        b0 = self.cfg.batch_sizes[0]
-        window = now - head.arrival_s
-        if len(cand) >= 3 and window > 0:
-            rate = (len(cand) - 1) / window
-            util_small = rate * service.estimate(
-                Bucket(b0, bucket.pooling)) / b0
-        else:
-            util_small = 0.0
-        if util_small < self.cfg.early_flush_util:
-            flush_by = min(flush_by,
-                           head.arrival_s + self.cfg.max_wait_ms * 1e-3)
-        if now >= flush_by or next_arrival is None:
-            return Flush(bucket, len(cand))
-        return Wait(min(flush_by, next_arrival))
+        """Flush now or wait; counter ``batcher.decide``."""
+        with SPANS.tally("batcher.decide"):
+            if not queued:
+                return None
+            b_max = self.cfg.batch_sizes[-1]
+            cand = queued[:b_max]
+            bucket = Bucket(self._batch_size(len(cand)),
+                            self._pooling_level(cand))
+            if len(cand) >= b_max:
+                return Flush(bucket, b_max)
+            head = cand[0]
+            flush_by = (head.deadline_s - service.estimate(bucket)
+                        - self.cfg.safety_ms * 1e-3)
+            b0 = self.cfg.batch_sizes[0]
+            window = now - head.arrival_s
+            if len(cand) >= 3 and window > 0:
+                rate = (len(cand) - 1) / window
+                util_small = rate * service.estimate(
+                    Bucket(b0, bucket.pooling)) / b0
+            else:
+                util_small = 0.0
+            if util_small < self.cfg.early_flush_util:
+                flush_by = min(flush_by,
+                               head.arrival_s + self.cfg.max_wait_ms * 1e-3)
+            if now >= flush_by or next_arrival is None:
+                return Flush(bucket, len(cand))
+            return Wait(min(flush_by, next_arrival))
 
 
 class FixedBatcher:

@@ -21,6 +21,8 @@ from typing import Dict, List
 
 import numpy as np
 
+from repro.core.spans import SPANS
+
 _ARRIVAL_TAG = 0x5EA1
 
 
@@ -146,10 +148,16 @@ class AdmissionQueue:
         return True
 
     def view(self) -> List[Request]:
-        """Current contents in arrival order (the batcher's read-only view)."""
-        return list(self._q)
+        """Current contents in arrival order (the batcher's read-only view);
+        counter ``queue.view``."""
+        with SPANS.tally("queue.view"):
+            return list(self._q)
 
     def pop_n(self, n: int) -> List[Request]:
+        """The first ``n`` requests: the start of a batch, where the span
+        recorder reads its on/off state; span ``queue.pop``."""
         if n > len(self._q):
             raise ValueError(f"pop_n({n}) from queue of {len(self._q)}")
-        return [self._q.popleft() for _ in range(n)]
+        SPANS.refresh()
+        with SPANS.span("queue.pop"):
+            return [self._q.popleft() for _ in range(n)]

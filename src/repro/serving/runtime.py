@@ -98,7 +98,8 @@ class ClosedLoopSource:
 
 class BindingExecutor:
     """Runs micro-batches on a real engine through the ``ServeBinding`` seam
-    (core/pifs.py), measuring device wall time."""
+    (core/pifs.py), measuring the host wall time of each whole call: host
+    to device, the step, and the wait for the device."""
 
     def __init__(self, binding):
         self.binding = binding
