@@ -1702,11 +1702,18 @@ class ServeBinding:
         # rebinder rebuilds the jitted serve-step variants for a new
         # engine/mesh pair (only loadgen knows model families, so it owns
         # the callable); prefer_tp parameterizes the survivor-mesh policy
-        self._rebind = None          # (engine, mesh) -> (step, steps|None)
+        self._rebind = None   # (engine, mesh) -> (step, steps, shardings)
         self.prefer_tp = 4
         self.remeshes = 0
         self.remesh_events: list = []
         self._carried_traces = 0     # pre-remesh steady traces (see remesh)
+        # (variant, batch shapes) -> where execute stages that batch
+        # (see _batch_shardings).  Each is checked once, after its first
+        # call: moved_inputs is the most step-argument leaves one of them
+        # found off the executable's input shardings (jit copies each of
+        # those on every call)
+        self._staging: dict = {}
+        self.moved_inputs = 0
 
     # ------------------------------------------------------------ variants
     def modes(self) -> tuple:
@@ -1726,8 +1733,9 @@ class ServeBinding:
         """Run one bucket-shaped batch and block until the device is done.
 
         Spans (``repro.core.spans``): ``serve.execute`` over ``serve.stage``
-        (id check, host to device of every entry), ``serve.dispatch`` (the
-        jitted step's call) and ``serve.block``."""
+        (id check, host to device of the batch onto the mesh, each leaf
+        where the step takes it), ``serve.dispatch`` (the jitted step's
+        call) and ``serve.block``."""
         SPANS.refresh()
         with SPANS.span("serve.execute"):
             with SPANS.span("serve.stage"):
@@ -1736,11 +1744,19 @@ class ServeBinding:
                     # the serve step is jitted: the OOB check must see the
                     # concrete host batch, before tracing swallows it
                     self.engine._check_ids(np.asarray(batch[self.idx_key]))
-                jb = {k: jnp.asarray(v) for k, v in batch.items()}
+                sig = (self.active,) + tuple(v.shape for v in batch.values())
+                where = self._staging.get(sig)
+                first = where is None
+                if first:
+                    where = self._staging[sig] = self._batch_shardings(batch)
+                jb = jax.device_put(batch, where)
+            step = self.steps[self.active]
             with SPANS.span("serve.dispatch"):
-                out = self.steps[self.active](self.params, self.state, jb)
+                out = step(self.params, self.state, jb)
             with SPANS.span("serve.block"):
                 jax.block_until_ready(out)
+            if first:
+                self._count_moved(step, jb)
             if self.scrub_scores:
                 scores = np.asarray(out)
                 finite = np.isfinite(scores)
@@ -1753,6 +1769,33 @@ class ServeBinding:
                 return out
             self.last_poisoned = 0
             return out
+
+    def _batch_shardings(self, batch: dict) -> dict:
+        """Where each leaf of a batch is staged: ``dense`` on the dense
+        towers' full-batch layout, over dp and tp
+        (``models.recsys._constrain_full_batch``), where its rows divide
+        over them; ids and weights over dp, as the lookup takes them; the
+        rest of each leaf replicated.  These are the layouts the compiler
+        picks for the serve steps' batch, which ``bind_model`` leaves to
+        it, so the step's call copies none of the batch."""
+        mesh, axes = self.engine.mesh, self.engine.axes
+        dp = NamedSharding(mesh, P(tuple(axes.dp) or None))
+        full = NamedSharding(mesh, P(tuple(axes.ep)))
+        n = axes.ep_size(mesh)
+        return {k: full if k == "dense" and v.shape[0] % n == 0 else dp
+                for k, v in batch.items()}
+
+    def _count_moved(self, step, jb: dict) -> None:
+        """Count the step's argument leaves placed other than its
+        executable takes them.  Lowering a signature that has been called
+        reuses its trace and its executable."""
+        args = (self.params, self.state, jb)
+        want = jax.tree.structure(args).flatten_up_to(
+            step.lower(*args).compile().input_shardings[0])
+        moved = sum(1 for a, s in zip(jax.tree.leaves(args), want)
+                    if s is not None
+                    and not a.sharding.is_equivalent_to(s, a.ndim))
+        self.moved_inputs = max(self.moved_inputs, moved)
 
     # ------------------------------------------------------------ integrity
     def attach_integrity(self, ledger=None, chunk: int = 64) -> None:
@@ -1884,10 +1927,11 @@ class ServeBinding:
     def attach_remesher(self, rebind, prefer_tp: int = 4) -> None:
         """Arm mid-serving elastic recovery.
 
-        ``rebind(engine, mesh) -> (step, steps|None)`` rebuilds the jitted
-        serve-step callable(s) for a re-meshed engine — only the model
-        binder (``serving.loadgen.bind_model``) knows the model family, so
-        it owns this closure.  ``prefer_tp`` parameterizes the
+        ``rebind(engine, mesh) -> (step, steps|None, param_shardings)``
+        rebuilds the jitted serve-step callable(s) for a re-meshed engine
+        and says where they take the params — only the model binder
+        (``serving.loadgen.bind_model``) knows the model family, so it owns
+        this closure.  ``prefer_tp`` parameterizes the
         survivor-mesh policy (``runtime/elastic.scale_plan``)."""
         self._rebind = rebind
         self.prefer_tp = int(prefer_tp)
@@ -1920,8 +1964,10 @@ class ServeBinding:
              (``runtime/elastic.remesh_engine``: int8 codes and carried
              per-page scales move verbatim — bit-stable, no requantize).
           5. Rebuild every jitted serve-step variant via the attached
-             rebinder; the caller re-warms them (warmup traces are not
-             steady-state) and resumes.
+             rebinder, and move the params onto the new mesh (they were
+             committed to the old one) and the batch's staging with it; the
+             caller re-warms them (warmup traces are not steady-state) and
+             resumes.
           6. If a checkpointer is attached, commit a post-remesh baseline
              snapshot — the old-mesh checkpoint can no longer restore in
              place, and the snapshot truncates the already-replayed WAL.
@@ -1973,7 +2019,10 @@ class ServeBinding:
             self.integrity.rebind(new_engine)
             self.integrity.note_tier_changes(
                 self.state, old_p2s, np.asarray(self.state.page_to_shard))
-        step, steps = self._rebind(new_engine, new_mesh)
+        step, steps, param_shardings = self._rebind(new_engine, new_mesh)
+        self.params = jax.device_put(self.params, param_shardings)
+        self._staging.clear()
+        self.moved_inputs = 0
         self.steps = dict(steps or {})
         self.steps.setdefault("full", step)
         self.step = self.steps["full"]
@@ -2141,9 +2190,12 @@ class ServeBinding:
         """Engine plan-cache stats plus the carried trace ledger: traces
         counted on pre-remesh engines accumulate here, so the zero-
         steady-state-retrace contract is measured across the whole run,
-        re-meshes included."""
+        re-meshes included.  ``moved_inputs`` (see ``__init__``) describes
+        the placement on the current mesh, so ``reset_plan_stats`` keeps
+        it."""
         out = self.engine.plan_stats()
         out["traces"] = out["traces"] + self._carried_traces
+        out["moved_inputs"] = self.moved_inputs
         return out
 
     def reset_plan_stats(self) -> None:

@@ -16,6 +16,7 @@ Request features are host numpy, one example each:
 from __future__ import annotations
 
 import dataclasses
+import functools
 from typing import Callable, List, Sequence, Tuple
 
 import jax
@@ -69,19 +70,31 @@ class LoadConfig:
 # ---------------------------------------------------------------------------
 
 
+def _serve_jit(engine, param_shardings):
+    """``jax.jit`` taking (params, state, batch) where ``bind_model`` and
+    the engine put params and state, so a call moves neither.  The batch's
+    layout is left to the compiler: ``ServeBinding.execute`` stages each
+    leaf where it puts it."""
+    return functools.partial(jax.jit, in_shardings=(
+        param_shardings, engine.state_shardings(), None))
+
+
 def _dlrm_steps(cfg, engine, mesh, *, mode, impl, block_l, dedup,
                 front_end, degraded_variants):
-    """Jitted serve-step variants for a DLRM (engine, mesh) pair — split
-    out of :func:`bind_model` so an elastic re-mesh can rebuild them
-    against the survivor mesh with identical knobs (``front_end`` re-
-    resolves per mesh: tp>1 picks ``fused_tp``, tp=1 plain fused)."""
-    step = jax.jit(dlrm_mod.make_serve_step(
+    """Jitted serve-step variants for a DLRM (engine, mesh) pair, and the
+    params' shardings they take — split out of :func:`bind_model` so an
+    elastic re-mesh can rebuild them against the survivor mesh with
+    identical knobs (``front_end`` re-resolves per mesh: tp>1 picks
+    ``fused_tp``, tp=1 plain fused)."""
+    param_shardings = prm.shardings(dlrm_mod.model_specs(cfg, mesh), mesh)
+    jit = _serve_jit(engine, param_shardings)
+    step = jit(dlrm_mod.make_serve_step(
         cfg, engine, mesh, mode=mode, impl=impl, block_l=block_l,
         dedup=dedup, front_end=front_end))
     steps = None
     if degraded_variants:
         def dlrm_step(**kw):
-            return jax.jit(dlrm_mod.make_serve_step(
+            return jit(dlrm_mod.make_serve_step(
                 cfg, engine, mesh, mode=mode, impl=impl,
                 block_l=block_l, **kw))
         hot_only = dlrm_step(dedup="off", front_end="split",
@@ -92,7 +105,7 @@ def _dlrm_steps(cfg, engine, mesh, *, mode, impl, block_l, dedup,
             "hot_only": hot_only,
             "shed": hot_only,
         }
-    return step, steps
+    return step, steps, param_shardings
 
 
 def _rec_steps(cfg, engine, offs, mesh, *, mode, impl, block_l, dedup,
@@ -100,17 +113,19 @@ def _rec_steps(cfg, engine, offs, mesh, *, mode, impl, block_l, dedup,
     """Rec-family analogue of :func:`_dlrm_steps` (``offs`` are page-size
     offsets — a function of storage, not of the mesh, so they carry
     verbatim across a re-mesh)."""
-    step = jax.jit(rec_mod.make_serve_step(
+    param_shardings = prm.shardings(rec_mod.model_specs(cfg, mesh), mesh)
+    jit = _serve_jit(engine, param_shardings)
+    step = jit(rec_mod.make_serve_step(
         cfg, engine, offs, mesh, mode=mode, impl=impl, block_l=block_l,
         dedup=dedup))
     steps = None
     if degraded_variants:
-        no_dedup = jax.jit(rec_mod.make_serve_step(
+        no_dedup = jit(rec_mod.make_serve_step(
             cfg, engine, offs, mesh, mode=mode, impl=impl,
             block_l=block_l, dedup="off"))
         steps = {"split_fe": step, "no_dedup": no_dedup,
                  "hot_only": no_dedup, "shed": no_dedup}
-    return step, steps
+    return step, steps, param_shardings
 
 
 def bind_model(cfg, mesh, mode: str = "pifs", impl: str = "jnp",
@@ -167,8 +182,8 @@ def bind_model(cfg, mesh, mode: str = "pifs", impl: str = "jnp",
         engine, _ = dlrm_mod.build_engine(cfg, mesh,
                                           hot_fraction=hot_fraction,
                                           storage=storage, dedup=dedup)
-        params = prm.initialize(dlrm_mod.model_specs(cfg, mesh), k_params)
-        step, steps = _dlrm_steps(
+        specs = dlrm_mod.model_specs(cfg, mesh)
+        step, steps, param_shardings = _dlrm_steps(
             cfg, engine, mesh, mode=mode, impl=impl, block_l=block_l,
             dedup=dedup, front_end=front_end,
             degraded_variants=degraded_variants)
@@ -183,8 +198,8 @@ def bind_model(cfg, mesh, mode: str = "pifs", impl: str = "jnp",
         engine, offs = rec_mod.build_engine(cfg, mesh,
                                             hot_fraction=hot_fraction,
                                             storage=storage, dedup=dedup)
-        params = prm.initialize(rec_mod.model_specs(cfg, mesh), k_params)
-        step, steps = _rec_steps(
+        specs = rec_mod.model_specs(cfg, mesh)
+        step, steps, param_shardings = _rec_steps(
             cfg, engine, offs, mesh, mode=mode, impl=impl,
             block_l=block_l, dedup=dedup,
             degraded_variants=degraded_variants)
@@ -197,6 +212,9 @@ def bind_model(cfg, mesh, mode: str = "pifs", impl: str = "jnp",
                 degraded_variants=degraded_variants)
     else:
         raise TypeError(f"unsupported serving config {type(cfg)}")
+    # placed once here: left uncommitted, jit would copy every parameter
+    # from device 0 to the rest of the mesh on each call
+    params = jax.device_put(prm.initialize(specs, k_params), param_shardings)
     state = engine.init_state(k_state)
     binding = ServeBinding(engine, state, params, step, idx_key=idx_key,
                            steps=steps, validate_ids=validate_ids,

@@ -579,7 +579,9 @@ def test_serving_survives_shard_loss_with_elastic_remesh(mesh, rmc1):
     availability holds, zero steady-state retraces across BOTH sides of
     the re-mesh, the front end re-resolves fused_tp at the survivor tp,
     and the recovered engine serves scores bit-identical to a fresh
-    engine packed onto the same degraded mesh."""
+    engine packed onto the same degraded mesh.  The params move onto the
+    survivor mesh with it, so no step argument is moved on a call, and a
+    served batch scores as it did before the re-mesh."""
     import jax
     from repro.runtime.fault_tolerance import StragglerWatchdog
     from repro.serving import (BatcherConfig, BindingExecutor, Bucket,
@@ -607,11 +609,19 @@ def test_serving_survives_shard_loss_with_elastic_remesh(mesh, rmc1):
                       arrival=ArrivalConfig(rate_qps=400.0, seed=2),
                       slo_ms=500.0, seed=2, storage="int8", dedup="on",
                       front_end="fused")
+    padder = make_padder(rmc1)
+    probes = {}
+    for b in bat.batch_sizes:
+        bucket = Bucket(b, rmc1.pooling)
+        probes[b] = padder([factory(i, bucket.pooling)
+                            for i in range(bucket.batch)], bucket)
     with mesh:
         for rung in binding.modes():
             binding.set_mode(rung)
             rt.warmup(factory)
         binding.set_mode("full")
+        before = {b: np.asarray(jax.device_get(binding.execute(p)))
+                  for b, p in probes.items()}
         rt.executor = fex
         binding.reset_plan_stats()
         s = rt.run(OpenLoopSource(request_stream(rmc1, load)))
@@ -636,6 +646,11 @@ def test_serving_survives_shard_loss_with_elastic_remesh(mesh, rmc1):
                    if r["requested"] == "fused"]
         assert fe_recs and all(r["resolved"] == "fused_tp" and r["tp"] == 2
                                for r in fe_recs)
+        # placement: params committed to the survivor mesh, nothing moved
+        for leaf in jax.tree.leaves(binding.params):
+            assert leaf.committed
+            assert leaf.sharding.mesh == binding.engine.mesh
+        assert binding.plan_stats()["moved_inputs"] == 0
 
         # bit-exactness: recovered binding vs a fresh engine packed onto
         # the same survivor mesh from the same logical triple + page table
@@ -646,14 +661,11 @@ def test_serving_survives_shard_loss_with_elastic_remesh(mesh, rmc1):
         fresh.state = fresh.engine.pack_state(
             codes, values, scales, table=binding.state.page_table,
             counts=np.asarray(jax.device_get(binding.state.counts)))
-        padder = make_padder(rmc1)
-        for b in bat.batch_sizes:
-            bucket = Bucket(b, rmc1.pooling)
-            probe = padder([factory(i, bucket.pooling)
-                            for i in range(bucket.batch)], bucket)
+        for b, probe in probes.items():
+            got = np.asarray(jax.device_get(binding.execute(probe)))
             np.testing.assert_array_equal(
-                np.asarray(jax.device_get(binding.execute(probe))),
-                np.asarray(jax.device_get(fresh.execute(probe))))
+                got, np.asarray(jax.device_get(fresh.execute(probe))))
+            np.testing.assert_array_equal(got, before[b])
 
 
 def test_fault_injected_serving_run_end_to_end(mesh, rmc1):
